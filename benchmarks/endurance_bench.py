@@ -33,8 +33,8 @@ slow leaks and drift show up, under two scenarios:
 
 Writes ``BENCH_endurance.json`` (provenance-stamped like every BENCH
 artifact).  ``--smoke`` is the CI-sized run; the full run rides
-``python -m benchmarks.run``.  ``--phase kill|recover --dir D`` are the
-subprocess halves of ``kill_recover`` (driven by the parent run, not by
+``python -m benchmarks.run``.  ``--phase export|kill|recover --dir D`` are the
+subprocess phases of ``kill_recover`` (driven by the parent run, not by
 hand).
 
     PYTHONPATH=src python -m benchmarks.endurance_bench [--smoke]
@@ -264,6 +264,14 @@ def _storm_plan():
     ], seed=7)
 
 
+def _phase_export(d: str) -> None:
+    """First child of ``kill_recover``: export the AOT artifact both later
+    phases boot from.  A child, not the parent, so that on a one-chip
+    host every process that touches the device runs alone."""
+    engine, _server = _make_server()
+    engine.export_artifact(os.path.join(d, "artifact"), buckets=(1, 2, 4))
+
+
 def _phase_kill(d: str) -> None:
     """Child half of ``kill_recover``: boot from the artifact, journal
     a request stream, serve a prefix of it, then SIGKILL ourselves with
@@ -324,8 +332,6 @@ def _kill_recover_scenario(smoke: bool) -> dict:
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     d = tempfile.mkdtemp(prefix="endurance_killrec_")
-    engine, _server = _make_server()
-    engine.export_artifact(os.path.join(d, "artifact"), buckets=(1, 2, 4))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root,
@@ -340,6 +346,16 @@ def _kill_recover_scenario(smoke: bool) -> dict:
             cwd=root)
 
     row: dict = {"scenario": "kill_recover", "requests": 24}
+    try:
+        p_exp = phase("export", 420)
+    except Exception as e:              # noqa: BLE001 — report, don't crash
+        row["ok"] = skipped(f"export phase spawn failed: {e}")
+        return row
+    if p_exp.returncode != 0:
+        row["ok"] = False
+        row["error"] = (f"export phase exited {p_exp.returncode}: "
+                        f"{p_exp.stderr[-500:]}")
+        return row
     try:
         p_kill = phase("kill", 420)
     except Exception as e:              # noqa: BLE001 — report, don't crash
@@ -376,13 +392,16 @@ def _kill_recover_scenario(smoke: bool) -> dict:
 def run(smoke: bool = False, out: str = "BENCH_endurance.json") -> dict:
     n = 64 if smoke else 500
     rate = 400.0 if smoke else 250.0
+    # The subprocess scenario runs first, while this process has not yet
+    # touched a device: its children must be able to take the chip.
+    killrec = _kill_recover_scenario(smoke)
     scenarios = [
         _run_scenario("steady", requests=n, rate_hz=rate,
                       warmup=16, slo_ms=250.0, rss_budget_mb=64.0),
         _run_scenario("fault_storm", requests=n, rate_hz=rate,
                       warmup=16, slo_ms=500.0, rss_budget_mb=64.0,
                       plan=_storm_plan()),
-        _kill_recover_scenario(smoke),
+        killrec,
     ]
     steady = scenarios[0]
     storm = scenarios[1]
@@ -459,7 +478,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(prog="benchmarks.endurance_bench")
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run; still writes BENCH_endurance.json")
-    ap.add_argument("--phase", choices=("kill", "recover"),
+    ap.add_argument("--phase", choices=("export", "kill", "recover"),
                     help="subprocess halves of kill_recover (internal)")
     ap.add_argument("--dir", dest="dir_",
                     help="shared artifact+journal dir for --phase")
@@ -467,7 +486,8 @@ def main(argv=None):
     if args.phase:
         if not args.dir_:
             ap.error("--phase requires --dir")
-        (_phase_kill if args.phase == "kill" else _phase_recover)(args.dir_)
+        {"export": _phase_export, "kill": _phase_kill,
+         "recover": _phase_recover}[args.phase](args.dir_)
         return
     run(smoke=args.smoke)
 

@@ -45,7 +45,7 @@ def _tuned(net: str):
     in_shape = (_BATCH, _HW, _HW, spec[0].c_in)
     types = infer_types(graph, in_shape)
     # persist=False: report *this* run's measurements, never warm-start
-    # stale winners from ~/.cache/repro/autotune.json.
+    # stale winners from <repo>/.cache/repro/autotune.json.
     tuner = Autotuner(candidates=("xla", "xla_pm1"), warmup=1, iters=2,
                       persist=False)
     choices = tuner.tune(graph, in_shape)

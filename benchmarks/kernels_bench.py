@@ -3,10 +3,10 @@
 Three comparisons per fig5 (YOLOv2-Tiny) binary conv layer, all bit-exact
 by construction, so the deltas are pure execution-engine effects:
 
-* **reduction**: the whole-tile vectorized xor+popcount reduction
-  (``reduction="vector"``) vs the historical per-word
-  ``fori_loop``+``dynamic_slice`` form (``reduction="loop"``) inside
-  ``xnor_popcount_matmul``, on the layer's im2col matmul shape.
+* **reduction**: the unrolled outer-product xor+popcount walk
+  (``reduction="vector"``) vs the per-word ``fori_loop`` form
+  (``reduction="loop"``) inside ``xnor_popcount_matmul``, on the layer's
+  im2col matmul shape.
 * **conv path**: the direct (im2col-free) fused kernel vs the im2col
   fused kernel on the layer's conv shape.
 * **chain**: the megakernel region starting at the layer (the layer's

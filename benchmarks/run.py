@@ -5,6 +5,8 @@ writes the machine-readable ``BENCH_kernels.json`` perf artifact), and the
 roofline reader (which consumes cached dry-run artifacts if present).
 Each harness prints a CSV block.
 
+Exits non-zero when any harness raised (after running the rest).
+
 ``--smoke`` runs CI-sized shapes — a fast regression tripwire that still
 writes the BENCH artifacts; ``--only {kernels,serving,workloads,
 endurance,coldstart}`` restricts the run to one suite (and composes with
@@ -44,6 +46,9 @@ def main(argv: list[str] | None = None) -> None:
                         help="run one suite instead of everything; "
                              "composes with --smoke")
     args = parser.parse_args(argv)
+    from repro.cache import configure_compile_cache
+
+    configure_compile_cache()
 
     if args.only is not None:
         _suite_runner(args.only)(smoke=args.smoke)
@@ -61,6 +66,7 @@ def main(argv: list[str] | None = None) -> None:
         return
 
     t3_rows = None
+    failed: list[str] = []
     for name, fn in (
             ("table2_model_size", table2_model_size.run),
             ("table3_runtime", table3_runtime.run),
@@ -80,12 +86,14 @@ def main(argv: list[str] | None = None) -> None:
         except Exception:
             print(f"!! {name} failed:")
             traceback.print_exc()
+            failed.append(name)
 
     try:
         table4_energy.run(t3_rows)
     except Exception:
         print("!! table4_energy failed:")
         traceback.print_exc()
+        failed.append("table4_energy")
 
     if pathlib.Path("artifacts/dryrun").exists():
         try:
@@ -93,9 +101,12 @@ def main(argv: list[str] | None = None) -> None:
         except Exception:
             print("!! roofline failed:")
             traceback.print_exc()
+            failed.append("roofline")
     else:
         print("# §Roofline: no artifacts/dryrun cache — run "
               "`python -m repro.launch.dryrun --all` first")
+    if failed:
+        raise SystemExit(f"benchmark phases failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -49,6 +49,9 @@ MESH_DEVICES = 4
 _MD_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
+# A forced host mesh is a CPU measurement: keep the child off any chip
+# (which the parent may hold) and out of chip rows.
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys
 sys.path.insert(0, {src!r})
 import json

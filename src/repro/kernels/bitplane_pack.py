@@ -12,46 +12,40 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.bitplanes import NUM_PLANES
 from repro.core.packing import WORD_BITS, num_words
-
-def _pack_w(width: int) -> jnp.ndarray:
-    """(1, 1, 1, width) int32 weights bit i -> 1<<i, built in-kernel.
-
-    Iota + shift keeps the kernel free of captured constants; bit 31 wraps
-    to INT32_MIN (correct modular int32 packing).
-    """
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, width), 3)
-    return jax.lax.shift_left(jnp.int32(1), shifts)
-
+from repro.kernels.xnor_popcount_matmul import compiler_params
 
 def _kernel(x_ref, o_ref, *, channels: int):
-    x = x_ref[...].astype(jnp.int32)          # (1, bh, bw, C)
+    x = x_ref[...]                             # (1, bh, w, C) int32
     cw = num_words(channels)
-    # One iota+shift for the whole kernel; per-word slices view into it
-    # (this used to be re-emitted 8*Cw times per block).
-    pack_w = _pack_w(WORD_BITS)
-    words = []
     for n in range(NUM_PLANES):
-        bits = (x >> n) & 1                   # (1, bh, bw, C)
         for wi in range(cw):
             lo = wi * WORD_BITS
-            hi = min(lo + WORD_BITS, channels)
-            chunk = bits[..., lo:hi]
-            words.append(jnp.sum(chunk * pack_w[..., :hi - lo], axis=-1,
-                                 dtype=jnp.int32))
-    o_ref[...] = jnp.stack(words, axis=-1)    # (1, bh, bw, 8*Cw)
+            word = None
+            for c in range(lo, min(lo + WORD_BITS, channels)):
+                bit = jax.lax.shift_left(
+                    (x[..., c:c + 1] >> n) & 1, jnp.int32(c - lo))
+                word = bit if word is None else word | bit
+            k = n * cw + wi
+            o_ref[..., k:k + 1] = word         # (1, bh, w, 1) lane column
 
 
 @functools.partial(jax.jit, static_argnames=("block_h", "interpret"))
-def bitplane_pack(x: jnp.ndarray, *, block_h: int = 32,
+def bitplane_pack(x: jnp.ndarray, *, block_h: int = 8,
                   interpret: bool = False) -> jnp.ndarray:
-    """x: (N, H, W, C) uint8/int -> (N, H, W, 8*Cw) int32 packed planes."""
+    """x: (N, H, W, C) uint8/int -> (N, H, W, 8*Cw) int32 packed planes.
+
+    Blocks are ``block_h`` whole image rows.  On the TPU both the C input
+    channels and the 8*Cw output words sit on the 128-lane minor axis, so
+    a row costs W x 128 x 4 bytes of VMEM in and out whatever C is: the
+    default of 8 rows keeps a 416-wide image's double-buffered blocks
+    near 7 MiB.
+    """
     n, h, w, c = x.shape
     x = x.astype(jnp.int32)  # widen on entry; kernel works on int32 lanes
     bh = min(block_h, h)
@@ -69,5 +63,6 @@ def bitplane_pack(x: jnp.ndarray, *, block_h: int = 32,
         out_shape=jax.ShapeDtypeStruct((n, gh * bh, w, NUM_PLANES * cw),
                                        jnp.int32),
         interpret=interpret,
+        compiler_params=compiler_params(("parallel", "parallel")),
     )(x)
     return out[:, :h]

@@ -1,103 +1,51 @@
-"""Pallas TPU kernel: direct (im2col-free) fused binary convolution.
+"""Direct (im2col-free) fused binary convolution: a one- or two-stage chain.
 
 The im2col wrapper around ``fused_conv_bn_binarize`` materializes a
 ``(N, OH, OW, KH*KW*Cw)`` patch tensor in HBM — KH*KW times the input's
 bytes — before the matmul ever runs.  daBNN (1908.05858) and Khan et al.
 (1808.00209) both measure that this patch traffic, not the popcounts,
-dominates BNN conv time.  This kernel removes it (DESIGN.md §5):
+dominates BNN conv time.  The direct form removes it (DESIGN.md §5): each
+grid step streams one packed NHWC input tile into VMEM once (halo reads
+through element-offset block dims), walks KH x KW as in-VMEM shifted row
+reads, and applies the integer threshold + 32-channel bit-pack — and,
+with a pool, the OR-pool (max-pool == windowed OR in the packed domain) —
+before the single packed store.  Neither the im2col patches nor the
+unpacked conv output (nor, with the pool, the pre-pool conv output) ever
+reach HBM.
 
-* each grid step streams one packed NHWC input tile **once** into VMEM
-  (overlapping halo reads via element-offset / ``pl.Unblocked`` block
-  indexing — consecutive spatial tiles re-read only the KH-1 / KW-1 halo),
-* the KH x KW window walk happens as *in-VMEM shifted reads*: per tap a
-  strided slice of the resident tile, xor'd against that tap's filter
-  words with the whole-tile vectorized popcount reduction
-  (``xnor_popcount_matmul.tile_counts``),
-* the epilogue applies the integer threshold (Eqns 5-9), bit-packs 32
-  output channels per int32 word in-register, and optionally OR-pools the
-  packed words (max-pool == windowed OR in the packed domain) before the
-  single packed store.
+That is exactly a chain of one conv stage (plus one pool stage), so this
+entry point builds the stage specs and runs the chain megakernel
+(:mod:`repro.kernels.chain_conv`): one kernel body serves both the
+per-node ``vpu_direct``/``vpu_direct_pool`` backends and ``vpu_chain``.
 
-Neither the im2col patches nor the unpacked conv output (nor, with the
-pool epilogue, the pre-pool conv output) ever reach HBM.
-
-Tile shape knobs — ``block_h`` / ``block_w`` (output rows/cols per step,
-*final* rows: pooled rows when the pool epilogue is on), ``block_n``
-(batch images per step), ``block_o`` (output filters per step, multiple of
-32) — are what ``runtime.autotune`` sweeps per node.  A pool epilogue with
-nonzero pool padding forces a single spatial tile (the pad is applied to
-the in-VMEM conv words, which must then all be resident).
+Tile knobs — ``block_h`` / ``block_w`` (final output rows/cols per step:
+pooled rows when the pool is on) and ``block_n`` (batch images per step)
+— are what ``runtime.autotune`` sweeps per node.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.packing import WORD_BITS
-from repro.kernels.fused_conv_bn_binarize import threshold_pack
-from repro.kernels.xnor_popcount_matmul import compiler_params, tile_counts
+from repro.kernels.chain_conv import StageSpec, chain_conv
 
 
-def _or_pool_words(words: jnp.ndarray, window: int, stride: int,
-                   out_h: int, out_w: int) -> jnp.ndarray:
-    """Windowed bitwise OR over packed words: (bn, ch, cw, nw) ->
-    (bn, out_h, out_w, nw).  0-words are 32 channels of -1 — the OR
-    identity — so padding never distorts the max."""
-    out = None
-    for i in range(window):
-        for j in range(window):
-            s = jax.lax.slice(
-                words,
-                (0, i, j, 0),
-                (words.shape[0], i + (out_h - 1) * stride + 1,
-                 j + (out_w - 1) * stride + 1, words.shape[3]),
-                (1, stride, stride, 1))
-            out = s if out is None else (out | s)
-    return out
+def direct_stages(kh: int, stride: int, pad: int, channels: int,
+                  pool_window: int | None = None,
+                  pool_stride: int | None = None,
+                  pool_pad: tuple[int, int] = (0, 0)
+                  ) -> tuple[StageSpec, ...]:
+    """The chain spec of one conv (+ OR-pool) node."""
+    stages = (StageSpec("conv", kernel=kh, stride=stride, pad_lo=pad,
+                        pad_hi=pad, channels=channels),)
+    if pool_window is not None:
+        stages += (StageSpec("pool", kernel=pool_window,
+                             stride=pool_stride or pool_window,
+                             pad_lo=pool_pad[0], pad_hi=pool_pad[1],
+                             channels=channels),)
+    return stages
 
 
-def _kernel(x_ref, w_ref, ww_ref, t_ref, s_ref, o_ref, *,
-            kh: int, kw: int, stride: int, cw_words: int,
-            conv_h: int, conv_w: int,
-            pool: tuple[int, int, tuple[int, int]] | None,
-            out_h: int, out_w: int):
-    x = x_ref[...]                               # (bn, ih, iw, Cw) resident
-    bn = x.shape[0]
-    npos = bn * conv_h * conv_w
-    acc = jnp.zeros((npos, w_ref.shape[0]), jnp.int32)
-    for di in range(kh):                         # KH x KW window walk:
-        for dj in range(kw):                     # in-VMEM shifted reads
-            tap = di * kw + dj
-            patch = jax.lax.slice(               # (bn, conv_h, conv_w, Cw)
-                x,
-                (0, di, dj, 0),
-                (bn, di + (conv_h - 1) * stride + 1,
-                 dj + (conv_w - 1) * stride + 1, cw_words),
-                (1, stride, stride, 1))
-            filt = w_ref[:, tap * cw_words:(tap + 1) * cw_words]
-            wwt = ww_ref[tap * cw_words:(tap + 1) * cw_words]
-            acc += tile_counts(patch.reshape(npos, cw_words), filt, wwt)
-
-    # Epilogue: integer threshold + in-register 32-channel pack (+ OR-pool).
-    words = threshold_pack(acc, t_ref[...][None, :], s_ref[...][None, :])
-    words = words.reshape(bn, conv_h, conv_w, -1)
-    if pool is not None:
-        pwin, pstr, ppad = pool
-        if ppad != (0, 0):
-            words = jnp.pad(words, ((0, 0), ppad, ppad, (0, 0)))
-        words = _or_pool_words(words, pwin, pstr, out_h, out_w)
-    o_ref[...] = words
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("kh", "kw", "stride", "pad", "pool_window",
-                     "pool_stride", "pool_pad", "block_h", "block_w",
-                     "block_n", "block_o", "interpret"))
 def direct_conv_bn_binarize(
         x_packed: jnp.ndarray, w_packed: jnp.ndarray,
         threshold: jnp.ndarray, sign_flip: jnp.ndarray,
@@ -106,107 +54,23 @@ def direct_conv_bn_binarize(
         pool_window: int | None = None, pool_stride: int | None = None,
         pool_pad: tuple[int, int] = (0, 0),
         block_h: int | None = None, block_w: int | None = None,
-        block_n: int = 1, block_o: int | None = None,
-        interpret: bool = False) -> jnp.ndarray:
+        block_n: int = 1, interpret: bool = False) -> jnp.ndarray:
     """Direct fused conv(+pool): packed NHWC in, packed NHWC out.
 
     x_packed: (N, H, W, Cw) int32 channel-packed input (for the bit-plane
         first layer, Cw is the flattened 8*Cw plane-word dim).
     w_packed: (O, KH*KW*Cw) int32 canonical filter layout
-        (``binary_conv.pack_conv_weights`` order).
+        (``binary_conv.pack_conv_weights`` order); square kernels only.
     threshold/sign_flip: (O,) folded integer epilogue (Eqns 5-9).
     word_weights: (KH*KW*Cw,) per-word weights (Eqn 2 bit-plane powers).
     Returns (N, OH', OW', ceil(O/32)) int32 where OH'/OW' are the conv
     output dims, pooled when ``pool_window`` is given.
     """
-    n, h, w_in, cw = x_packed.shape
-    o, pw = w_packed.shape
-    assert pw == kh * kw * cw, (w_packed.shape, (kh, kw, cw))
-    if word_weights is None:
-        word_weights = jnp.ones((pw,), jnp.int32)
-
-    oh = (h + 2 * pad - kh) // stride + 1
-    ow = (w_in + 2 * pad - kw) // stride + 1
-    if pool_window is not None:
-        pstr = pool_stride or pool_window
-        fh = (oh + sum(pool_pad) - pool_window) // pstr + 1
-        fw = (ow + sum(pool_pad) - pool_window) // pstr + 1
-        pool = (pool_window, pstr, tuple(pool_pad))
-    else:
-        pstr, pool = 1, None
-        fh, fw = oh, ow
-
-    # Tile shapes (the autotuner's knobs).  Nonzero pool padding must see
-    # the whole conv output at once -> single spatial tile.
-    bh = min(block_h or 8, fh)
-    bw = min(block_w or fw, fw)
-    if pool is not None and tuple(pool_pad) != (0, 0):
-        bh, bw = fh, fw
-    bn = max(1, min(block_n, n))
-    nw_valid = -(-o // WORD_BITS)
-    bo = min(block_o or 128, nw_valid * WORD_BITS)
-    bo = max(WORD_BITS, (bo // WORD_BITS) * WORD_BITS)
-
-    gn, gh, gw, go = (pl.cdiv(n, bn), pl.cdiv(fh, bh), pl.cdiv(fw, bw),
-                      pl.cdiv(nw_valid * WORD_BITS, bo))
-
-    single_spatial = (gh == 1 and gw == 1)
-    if pool is not None and not single_spatial:
-        # Tiled pool epilogue: each tile covers whole pool windows.
-        conv_h, conv_w = (bh - 1) * pstr + pool_window, \
-                         (bw - 1) * pstr + pool_window
-        rstep, cstep = bh * pstr * stride, bw * pstr * stride
-    elif pool is not None:
-        conv_h, conv_w = oh, ow
-        rstep = cstep = 0
-    else:
-        conv_h, conv_w = bh, bw
-        rstep, cstep = bh * stride, bw * stride
-    ih = (conv_h - 1) * stride + kh
-    iw = (conv_w - 1) * stride + kw
-
-    # Spatial pad: conv padding (0-words == -1 channels, DESIGN.md §3.2)
-    # plus bottom/right slack so every halo read stays in bounds.
-    need_h = (gh - 1) * rstep + ih
-    need_w = (gw - 1) * cstep + iw
-    x_packed = jnp.pad(x_packed, (
-        (0, gn * bn - n),
-        (pad, max(pad, need_h - h - pad)),
-        (pad, max(pad, need_w - w_in - pad)),
-        (0, 0)))
-
-    # Output-channel pad: threshold=-1 / sign=0 -> pad bits are 0, matching
-    # ``packing.pack_bits`` semantics.
-    o_pad = go * bo
-    w_packed = jnp.pad(w_packed, ((0, o_pad - o), (0, 0)))
-    threshold = jnp.pad(threshold.astype(jnp.int32), (0, o_pad - o),
-                        constant_values=-1)
-    sign_flip = jnp.pad(sign_flip.astype(jnp.int32), (0, o_pad - o))
-    word_weights = word_weights.astype(jnp.int32)
-
-    nwb = bo // WORD_BITS
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel, kh=kh, kw=kw, stride=stride, cw_words=cw,
-            conv_h=conv_h, conv_w=conv_w, pool=pool, out_h=bh, out_w=bw),
-        grid=(gn, gh, gw, go),
-        in_specs=[
-            # Element-offset (Unblocked) spec: overlapping halo reads.
-            pl.BlockSpec(
-                (bn, ih, iw, cw),
-                lambda ni, hi, wi, oi: (ni * bn, hi * rstep, wi * cstep, 0),
-                indexing_mode=pl.Unblocked()),
-            pl.BlockSpec((bo, pw), lambda ni, hi, wi, oi: (oi, 0)),
-            pl.BlockSpec((pw,), lambda ni, hi, wi, oi: (0,)),
-            pl.BlockSpec((bo,), lambda ni, hi, wi, oi: (oi,)),
-            pl.BlockSpec((bo,), lambda ni, hi, wi, oi: (oi,)),
-        ],
-        out_specs=pl.BlockSpec(
-            (bn, bh, bw, nwb), lambda ni, hi, wi, oi: (ni, hi, wi, oi)),
-        out_shape=jax.ShapeDtypeStruct(
-            (gn * bn, gh * bh, gw * bw, go * nwb), jnp.int32),
-        interpret=interpret,
-        **compiler_params(
-            interpret, ("parallel", "parallel", "parallel", "parallel")),
-    )(x_packed, w_packed, word_weights, threshold, sign_flip)
-    return out[:n, :fh, :fw, :nw_valid]
+    if kh != kw:
+        raise ValueError(f"direct conv needs a square kernel, got {kh}x{kw}")
+    stages = direct_stages(kh, stride, pad, w_packed.shape[0], pool_window,
+                           pool_stride, tuple(pool_pad))
+    return chain_conv(x_packed, stages,
+                      (w_packed, word_weights, threshold, sign_flip),
+                      block_h=block_h, block_w=block_w, block_n=block_n,
+                      interpret=interpret)

@@ -37,31 +37,44 @@ from repro.kernels.xnor_popcount_matmul import _tile_counts, compiler_params
 
 
 def pack_words(bits: jnp.ndarray) -> jnp.ndarray:
-    """In-register bit-pack of the minor axis: (..., n*32) {0,1} int32 ->
-    (..., n) int32 words, LSB-first.
+    """In-register bit-pack of the minor axis: (m, n*32) {0,1} int32 ->
+    (m, n) int32 words, LSB-first.
 
-    The weights are built from a broadcasted iota + shift so kernel bodies
-    have no captured constants (Pallas requires all operands explicit).
-    Bit 31 wraps to INT32_MIN — the correct two's-complement pattern for
-    modular int32 accumulation.
+    Done as two small matmuls against a block-diagonal power-of-two
+    matrix built from iotas (Pallas kernels take no captured constants):
+    one for bits 0-15, one for bits 16-31.  Every product is a 0/1 bf16
+    times an exact bf16 power of two and every sum stays below 2^16, so
+    the f32 accumulation is exact; the halves recombine with a shift
+    (bit 31 wraps to INT32_MIN — the two's-complement pattern modular
+    int32 accumulation expects).  A lane-splitting reshape would say the
+    same thing, but Mosaic does not lower one.
     """
-    shape = bits.shape[:-1] + (bits.shape[-1] // WORD_BITS, WORD_BITS)
-    words = bits.reshape(shape)
-    shifts = jax.lax.broadcasted_iota(jnp.int32, words.shape, words.ndim - 1)
-    return jnp.sum(words * jax.lax.shift_left(jnp.int32(1), shifts),
-                   axis=-1, dtype=jnp.int32)
+    n_bits = bits.shape[-1]
+    shape = (n_bits, n_bits // WORD_BITS)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    bit = row % WORD_BITS
+    own = (row // WORD_BITS) == col
+    power = jax.lax.shift_left(jnp.int32(1), bit % 16).astype(jnp.float32)
+    lo = jnp.where(own & (bit < 16), power, 0.0).astype(jnp.bfloat16)
+    hi = jnp.where(own & (bit >= 16), power, 0.0).astype(jnp.bfloat16)
+    b = bits.astype(jnp.bfloat16)
+    w_lo = jnp.dot(b, lo, preferred_element_type=jnp.float32)
+    w_hi = jnp.dot(b, hi, preferred_element_type=jnp.float32)
+    return w_lo.astype(jnp.int32) | jax.lax.shift_left(
+        w_hi.astype(jnp.int32), 16)
 
 
 def threshold_pack(cnt: jnp.ndarray, t: jnp.ndarray,
                    s: jnp.ndarray) -> jnp.ndarray:
     """Fused epilogue on a count tile: integer threshold (Eqn 9's
     ``(cnt <= t) xor s`` form) + in-register 32-channel bit-pack.
-    cnt: (..., bn); t, s: (bn,) int32 -> (..., bn//32) int32 words."""
+    cnt: (m, n); t, s: (1, n) int32 -> (m, n//32) int32 words."""
     bits = (jnp.less_equal(cnt, t).astype(jnp.int32) ^ s)
     return pack_words(bits)
 
 
-def _kernel(a_ref, b_ref, ww_ref, t_ref, s_ref, o_ref, acc_ref,
+def _kernel(a_ref, bt_ref, ww_ref, t_ref, s_ref, o_ref, acc_ref,
             *, n_k_steps: int, reduction: str):
     k = pl.program_id(2)
 
@@ -69,13 +82,12 @@ def _kernel(a_ref, b_ref, ww_ref, t_ref, s_ref, o_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _tile_counts(a_ref[...], b_ref[...], ww_ref[...],
+    acc_ref[...] += _tile_counts(a_ref[...], bt_ref[...], ww_ref[...],
                                  reduction)
 
     @pl.when(k == n_k_steps - 1)
     def _epilogue():
-        o_ref[...] = threshold_pack(acc_ref[...], t_ref[...][None, :],
-                                    s_ref[...][None, :])
+        o_ref[...] = threshold_pack(acc_ref[...], t_ref[...], s_ref[...])
 
 
 @functools.partial(
@@ -85,14 +97,17 @@ def _kernel(a_ref, b_ref, ww_ref, t_ref, s_ref, o_ref, acc_ref,
 def fused_matmul_bn_binarize(a: jnp.ndarray, b: jnp.ndarray,
                              threshold: jnp.ndarray, sign_flip: jnp.ndarray,
                              word_weights: jnp.ndarray | None = None,
-                             *, block_m: int = 128, block_n: int = 256,
+                             *, block_m: int = 128,
+                             block_n: int | None = None,
                              block_k: int = 128, reduction: str = "vector",
                              interpret: bool = False) -> jnp.ndarray:
     """a: (M, W) patches, b: (N, W) filters -> packed bits (M, ceil(N/32)).
 
     threshold: (N,) int32; sign_flip: (N,) bool.  Output channel padding
     (N -> block multiple) uses threshold=-1 / sign=0 so pad bits are 0,
-    matching ``packing.pack_bits`` semantics.
+    matching ``packing.pack_bits`` semantics.  The default ``block_n``
+    (None) spans every filter: the packed output block is then the whole
+    word dim, which the TPU (8, 128) block rule always accepts.
     """
     m, w = a.shape
     n, wb = b.shape
@@ -101,16 +116,21 @@ def fused_matmul_bn_binarize(a: jnp.ndarray, b: jnp.ndarray,
         word_weights = jnp.ones((w,), jnp.int32)
 
     bm, bk = min(block_m, m), min(block_k, w)
-    bn = min(block_n, max(WORD_BITS, n))
-    bn = max(WORD_BITS, (bn // WORD_BITS) * WORD_BITS)
+    if block_n is None:
+        bn = -(-n // WORD_BITS) * WORD_BITS
+    else:
+        bn = min(block_n, max(WORD_BITS, n))
+        bn = max(WORD_BITS, (bn // WORD_BITS) * WORD_BITS)
     gm, gn, gk = pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(w, bk)
 
     a = jnp.pad(a, ((0, gm * bm - m), (0, gk * bk - w)))
     b = jnp.pad(b, ((0, gn * bn - n), (0, gk * bk - w)))
-    word_weights = jnp.pad(word_weights.astype(jnp.int32), (0, gk * bk - w))
+    word_weights = jnp.pad(word_weights.astype(jnp.int32),
+                           (0, gk * bk - w)).reshape(1, gk * bk)
     threshold = jnp.pad(threshold.astype(jnp.int32), (0, gn * bn - n),
-                        constant_values=-1)
-    sign_flip = jnp.pad(sign_flip.astype(jnp.int32), (0, gn * bn - n))
+                        constant_values=-1).reshape(1, gn * bn)
+    sign_flip = jnp.pad(sign_flip.astype(jnp.int32),
+                        (0, gn * bn - n)).reshape(1, gn * bn)
 
     nw = bn // WORD_BITS
     out = pl.pallas_call(
@@ -118,15 +138,15 @@ def fused_matmul_bn_binarize(a: jnp.ndarray, b: jnp.ndarray,
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, bk), lambda i, j, k: (0, k)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, nw), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * nw), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        **compiler_params(interpret),
-    )(a, b, word_weights, threshold, sign_flip)
+        compiler_params=compiler_params(),
+    )(a, b.T, word_weights, threshold, sign_flip)
     return out[:m, : -(-n // WORD_BITS)]

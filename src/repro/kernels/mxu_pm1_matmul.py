@@ -8,8 +8,12 @@ the systolic array:  dot_pm1(A, B) == K - 2*cnt  directly.
 
 This keeps PhoneBit's storage/bandwidth win (HBM traffic stays packed, 32x
 compressed — the paper's C2 layout) while swapping the compute engine for
-the one TPUs are built around.  See EXPERIMENTS.md §Perf for the comparison
-against the paper-faithful VPU kernel.
+the one TPUs are built around.  Whether it beats the paper-faithful VPU
+kernel on a given layer is a per-layer chip measurement (the autotuner
+decides per node).
+
+The default ``block_k=128`` keeps the word block legal under the TPU
+(8, 128) block rule: ``min(128, W)`` is either 128 or the whole word dim.
 """
 
 from __future__ import annotations
@@ -22,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import WORD_BITS
+from repro.kernels.xnor_popcount_matmul import compiler_params
 
 
 def _unpack_pm1(words: jnp.ndarray) -> jnp.ndarray:
@@ -54,7 +59,7 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k_steps: int):
     jax.jit,
     static_argnames=("k_valid", "block_m", "block_n", "block_k", "interpret"))
 def mxu_pm1_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k_valid: int,
-                   block_m: int = 128, block_n: int = 128, block_k: int = 16,
+                   block_m: int = 128, block_n: int = 128, block_k: int = 128,
                    interpret: bool = False) -> jnp.ndarray:
     """a: (M, W) int32, b: (N, W) int32 -> +-1 dots (M, N) int32 (Eqn 1).
 
@@ -70,14 +75,6 @@ def mxu_pm1_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k_valid: int,
     a = jnp.pad(a, ((0, gm * bm - m), (0, gk * bk - w)))
     b = jnp.pad(b, ((0, gn * bn - n), (0, gk * bk - w)))
 
-    kwargs = {}
-    if not interpret:
-        params = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-        if params is not None:
-            kwargs["compiler_params"] = params(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     out = pl.pallas_call(
         functools.partial(_kernel, n_k_steps=gk),
         grid=(gm, gn, gk),
@@ -89,7 +86,7 @@ def mxu_pm1_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k_valid: int,
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(),
     )(a, b)
     pad_bits = gk * bk * WORD_BITS - k_valid
     return out[:m, :n] - pad_bits

@@ -14,7 +14,9 @@ Conv backends (``CONV_MODES``):
 * ``"vpu_popcount"``  — paper-faithful xor+popcount Pallas kernel on
                         im2col patches (C1); the legacy im2col path, kept
                         as a selectable backend.
-* ``"mxu_pm1"``       — beyond-paper MXU kernel (unpack-to-bf16 in VMEM).
+* ``"mxu_pm1"``       — beyond-paper MXU kernel (unpack-to-bf16 in VMEM) on
+                        im2col patches; the weighted bit-plane first layer
+                        takes the pure-JAX path.
 * ``"xla"/"xla_pm1"`` — pure-JAX fallbacks (always available; what
                         benchmarks time on CPU and what models use under
                         jit on any backend).
@@ -82,6 +84,14 @@ def fused_matmul_bn_binarize(a, b, p: layer_integration.IntegratedParams,
         return _fused_mod.fused_matmul_bn_binarize(
             a, b, p.threshold, p.sign_flip, word_weights,
             interpret=_interpret(), **block_kw)
+    if mode == "mxu_pm1" and word_weights is None:
+        # Unweighted layers ride the MXU kernel; the bit-plane first
+        # layer's weighted counts take the xor path as ``xla_pm1`` does.
+        total = a.shape[-1] * packing.WORD_BITS
+        dot = _mxu_mod.mxu_pm1_matmul(a, b, k_valid=total,
+                                      interpret=_interpret(), **block_kw)
+        bits = layer_integration.apply_threshold((total - dot) // 2, p)
+        return packing.pack_bits(bits, axis=-1)
     if mode in _IMPL:
         cnt = binary_ops.packed_matmul_counts(
             a, b, word_weights=word_weights, impl=_IMPL[mode])
@@ -122,7 +132,7 @@ def fused_binary_conv2d(x_packed: jnp.ndarray, w_packed: jnp.ndarray,
             kh=kh, kw=kw, stride=stride, pad=pad,
             word_weights=word_weights, interpret=_interpret(),
             **pool_kw, **block_kw)
-    if mode == "vpu_popcount":
+    if mode in ("vpu_popcount", "mxu_pm1"):
         flat, (n, oh, ow) = im2col_matmul(x_packed, kh, kw, stride, pad)
         out = fused_matmul_bn_binarize(
             flat, w_packed, p, word_weights=word_weights, mode=mode,

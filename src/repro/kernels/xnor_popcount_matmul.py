@@ -12,15 +12,20 @@ dim), which is the Pallas analogue of the paper's private-memory per-thread
 accumulation (C6); Pallas double-buffers the a/b block DMAs against compute
 (C7, latency hiding).
 
-The inner reduction is *whole-tile vectorized* (DESIGN.md §5.2): one
-block-level xor of the broadcast (bm, bn, bk) cube, one population_count,
-one weighted reduction over the word axis — every VPU lane busy every
-cycle.  The historical per-word ``fori_loop`` + ``dynamic_slice`` form is
-kept selectable (``reduction="loop"``) purely so benchmarks/kernels_bench
-can measure the win; it is not a serving path.
+The inner reduction is an *outer-product walk* over the packed words
+(DESIGN.md §5.2): ``b`` arrives transposed, (W, N), so word w of every
+filter is one sublane row; word w of every patch is one lane column of
+``a``.  Each step xors the lane-broadcast column against the
+sublane-broadcast row — a whole (bm, bn) VPU op with output channels on
+the lanes — then popcounts and accumulates.  Every operand stays 2-D, which
+is what the TPU's Mosaic compiler lowers.  The per-word ``fori_loop`` form
+is kept selectable (``reduction="loop"``) purely so benchmarks/kernels_bench
+can measure the unrolled walk against it; it is not a serving path.
 
-The optional per-word weight vector ``ww`` implements Eqn 2's bit-plane
-powers 2^(n-1) so the first layer reuses this same kernel.
+The optional per-word weight row ``ww`` (1, W) implements Eqn 2's
+bit-plane powers 2^(n-1) so the first layer reuses this same kernel.
+Block shapes follow the TPU (8, 128) rule: the defaults ``min(128, dim)``
+are either 128 or the whole dim, so they are legal for every shape.
 """
 
 from __future__ import annotations
@@ -34,59 +39,50 @@ from jax.experimental.pallas import tpu as pltpu
 
 REDUCTIONS = ("vector", "loop")
 
-
-# Word-axis width below which the broadcast cube is not worth building:
-# with only a handful of packed words the reduction fully unrolls at trace
-# time into straight-line whole-tile (bm, bn) ops — no cube, no loop
-# state, every step a full VPU op with output channels on the lanes.
-_NARROW_K = 16
-# Word-axis slab per broadcast cube: bounds the live (bm, bn, _SLAB_K)
-# intermediate to ~2 MiB at 128x128 blocks so it fits VMEM alongside the
-# double-buffered operand blocks even at the largest default tiles.
-_SLAB_K = 32
+# Scoped-VMEM ceiling handed to Mosaic for every kernel of the packed path.
+# A v5e TensorCore has 128 MiB of VMEM; Mosaic's default scope is 16 MiB,
+# which the halo-tiled conv kernels outgrow at 416x416 inputs.
+VMEM_LIMIT_BYTES = 96 * 2 ** 20
 
 
-def tile_counts(a: jnp.ndarray, b: jnp.ndarray,
+def tile_counts(a: jnp.ndarray, bt: jnp.ndarray,
                 ww: jnp.ndarray) -> jnp.ndarray:
-    """Whole-tile vectorized weighted xor-popcount: (bm, bk) x (bn, bk) ->
-    (bm, bn) int32.  No per-word ``dynamic_slice`` and no ``fori_loop``:
-    wide word dims do broadcast xor -> population_count -> weighted
-    reduction over the minor (word) axis, in static ``_SLAB_K``-word slabs
-    so the (bm, bn, slab) cube stays VMEM-sized; narrow word dims
-    (< ``_NARROW_K``) unroll statically into bk fused whole-tile ops,
-    which beats both the cube (nothing materialized) and the loop
-    (no loop-carried state)."""
-    bk = a.shape[1]
-    if bk < _NARROW_K:
-        bt = jnp.transpose(b)                                  # (bk, bn)
-        acc = None
-        for w in range(bk):
-            c = jax.lax.population_count(
-                jax.lax.bitwise_xor(a[:, w:w + 1], bt[w:w + 1, :])) * ww[w]
-            acc = c if acc is None else acc + c
-        return acc
+    """Weighted xor-popcount of a (m, k) word tile against a transposed
+    (k, n) filter tile with a (1, k) weight row -> (m, n) int32.
+
+    Statically unrolled over the k words: each step is one whole-tile
+    xor / population_count / multiply-add on (m, n), with the patch word
+    broadcast along lanes and the filter word along sublanes."""
     acc = None
-    for s in range(0, bk, _SLAB_K):
-        e = min(s + _SLAB_K, bk)
-        x = jax.lax.bitwise_xor(a[:, None, s:e], b[None, :, s:e])
-        cnt = jnp.sum(jax.lax.population_count(x) * ww[None, None, s:e],
-                      axis=-1, dtype=jnp.int32)               # (bm, bn)
-        acc = cnt if acc is None else acc + cnt
+    for w in range(a.shape[1]):
+        c = jax.lax.population_count(
+            jax.lax.bitwise_xor(a[:, w:w + 1], bt[w:w + 1, :])) \
+            * ww[:, w:w + 1]
+        acc = c if acc is None else acc + c
     return acc
 
 
-def tile_counts_loop(a: jnp.ndarray, b: jnp.ndarray,
+def tile_counts_loop(a: jnp.ndarray, bt: jnp.ndarray,
                      ww: jnp.ndarray) -> jnp.ndarray:
-    """Legacy per-word reduction (benchmark baseline only): one packed word
-    per ``fori_loop`` step via ``dynamic_slice`` — scalar-ish on the VPU."""
-    def body(w, acc):
-        aw = jax.lax.dynamic_slice_in_dim(a, w, 1, axis=1)       # (bm, 1)
-        bw = jax.lax.dynamic_slice_in_dim(b, w, 1, axis=1)       # (bn, 1)
-        www = jax.lax.dynamic_slice_in_dim(ww, w, 1, axis=0)     # (1,)
-        x = jax.lax.bitwise_xor(aw, jnp.transpose(bw))           # (bm, bn)
-        return acc + jax.lax.population_count(x) * www[0]
+    """Per-word ``fori_loop`` reduction (benchmark baseline only): one
+    packed word per step, the word's column picked out of ``a`` by a
+    masked lane reduction (and its filter row by a masked sublane
+    reduction) — scalar-ish on the VPU."""
+    lane_a = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    lane_w = jax.lax.broadcasted_iota(jnp.int32, ww.shape, 1)
+    row_b = jax.lax.broadcasted_iota(jnp.int32, bt.shape, 0)
 
-    init = jnp.zeros((a.shape[0], b.shape[0]), jnp.int32)
+    def body(w, acc):
+        aw = jnp.sum(jnp.where(lane_a == w, a, 0), axis=1, keepdims=True,
+                     dtype=jnp.int32)                          # (m, 1)
+        www = jnp.sum(jnp.where(lane_w == w, ww, 0), axis=1, keepdims=True,
+                      dtype=jnp.int32)                         # (1, 1)
+        bw = jnp.sum(jnp.where(row_b == w, bt, 0), axis=0, keepdims=True,
+                     dtype=jnp.int32)                          # (1, n)
+        return acc + jax.lax.population_count(
+            jax.lax.bitwise_xor(aw, bw)) * www
+
+    init = jnp.zeros((a.shape[0], bt.shape[1]), jnp.int32)
     return jax.lax.fori_loop(0, a.shape[1], body, init)
 
 
@@ -98,7 +94,7 @@ def _tile_counts(a, b, ww, reduction: str):
     raise ValueError(f"unknown reduction {reduction!r}; want {REDUCTIONS}")
 
 
-def _kernel(a_ref, b_ref, ww_ref, o_ref, acc_ref, *, n_k_steps: int,
+def _kernel(a_ref, bt_ref, ww_ref, o_ref, acc_ref, *, n_k_steps: int,
             reduction: str):
     k = pl.program_id(2)
 
@@ -106,7 +102,7 @@ def _kernel(a_ref, b_ref, ww_ref, o_ref, acc_ref, *, n_k_steps: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _tile_counts(a_ref[...], b_ref[...], ww_ref[...],
+    acc_ref[...] += _tile_counts(a_ref[...], bt_ref[...], ww_ref[...],
                                  reduction)
 
     @pl.when(k == n_k_steps - 1)
@@ -114,17 +110,12 @@ def _kernel(a_ref, b_ref, ww_ref, o_ref, acc_ref, *, n_k_steps: int,
         o_ref[...] = acc_ref[...]
 
 
-def compiler_params(interpret: bool,
-                    semantics=("parallel", "parallel", "arbitrary")) -> dict:
-    """kwargs for ``pl.pallas_call`` carrying the TPU dimension semantics
-    (version-portable; empty off-TPU / in interpret mode)."""
-    if interpret:
-        return {}
-    params = getattr(pltpu, "CompilerParams",
-                     getattr(pltpu, "TPUCompilerParams", None))
-    if params is None:
-        return {}
-    return {"compiler_params": params(dimension_semantics=semantics)}
+def compiler_params(semantics=("parallel", "parallel", "arbitrary")
+                    ) -> pltpu.CompilerParams:
+    """TPU dimension semantics plus the packed path's VMEM ceiling
+    (ignored in interpret mode)."""
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 @functools.partial(
@@ -150,20 +141,20 @@ def xnor_popcount_matmul(a: jnp.ndarray, b: jnp.ndarray,
     a = jnp.pad(a, ((0, gm * bm - m), (0, gk * bk - w)))
     b = jnp.pad(b, ((0, gn * bn - n), (0, gk * bk - w)))
     word_weights = jnp.pad(word_weights.astype(jnp.int32),
-                           (0, gk * bk - w))
+                           (0, gk * bk - w)).reshape(1, gk * bk)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_k_steps=gk, reduction=reduction),
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, bk), lambda i, j, k: (0, k)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        **compiler_params(interpret),
-    )(a, b, word_weights)
+        compiler_params=compiler_params(),
+    )(a, b.T, word_weights)
     return out[:m, :n]

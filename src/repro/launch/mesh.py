@@ -9,19 +9,12 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-    _HAVE_AXIS_TYPE = True
-except ImportError:  # older jax: meshes are implicitly "auto" on every axis
-    AxisType = None
-    _HAVE_AXIS_TYPE = False
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if _HAVE_AXIS_TYPE:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -45,6 +45,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.cache import configure_compile_cache
 from repro.distributed.sharding import rules_for_mesh
 from repro.launch.mesh import make_host_mesh
 from repro.models import paper_nets, transformer
@@ -66,6 +67,15 @@ def _print_metrics(tag: str, m: dict) -> None:
         mode = f", mode {m['mode']}" if m.get("mode") else ""
         print(f"[{tag}] resilience: "
               + ", ".join(f"{k} {v}" for k, v in res.items()) + mode)
+
+
+def shard_mesh():
+    """The ``--shard`` data-parallel mesh over every local device; one
+    device has nothing to shard over, so that is an error, not a no-op."""
+    n = len(jax.devices())
+    if n < 2:
+        raise SystemExit(f"--shard needs at least 2 devices, found {n}")
+    return make_host_mesh(data=n, model=1)
 
 
 def serve_bnn(args) -> dict:
@@ -100,9 +110,7 @@ def serve_bnn(args) -> dict:
               f"mode {meta['mode']})")
         return meta
 
-    mesh = None
-    if args.shard and len(jax.devices()) > 1:
-        mesh = make_host_mesh(data=len(jax.devices()), model=1)
+    mesh = shard_mesh() if args.shard else None
     journal = None
     if args.journal:
         from repro.serving.recovery import RequestJournal, replay_journal
@@ -307,6 +315,7 @@ def main(argv=None):
                          "Chrome/Perfetto trace-event JSON here "
                          "(chrome://tracing / ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     tracer = obs_trace.install() if args.trace_out else None
     try:
         if args.workloads:

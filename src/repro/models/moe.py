@@ -38,7 +38,6 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.sharding import Rules
 from repro.models import layers
 
-from repro import compat
 
 
 def padded_experts(n_experts: int, ep: int) -> int:
@@ -180,7 +179,7 @@ def moe_apply(x_tokens: jnp.ndarray, router, wg, wu, wd, *,
     # check_vma=False: when tokens are replicated over the model axis
     # (decode), the static variance checker cannot prove the all_to_all
     # round-trip keeps them replicated; the collectives are still correct.
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body, mesh=rules.mesh,
         in_specs=(tok_spec, P(None, None), P(rules.model, None, None),
                   P(rules.model, None, None), P(rules.model, None, None)),

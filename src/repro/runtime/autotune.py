@@ -39,7 +39,7 @@ space, since a chain tile couples every stage through halo growth — and
 caches winners under ``chain::``-prefixed chain-shaped signatures (stage
 specs + entry shape + device kind) in the same stores.
 
-The cache additionally persists to disk (``~/.cache/repro/autotune.json``,
+The cache additionally persists to disk (``<repo>/.cache/repro/autotune.json``,
 keyed by the same signatures — which embed the device kind) so repeated
 engine startups skip re-timing entirely.  ``REPRO_AUTOTUNE_CACHE=0``
 disables persistence; any other value overrides the cache file path.
@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cache import AUTOTUNE_FILE
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _trace
 from repro.runtime.executor import (BACKENDS, GraphExecutor, eval_node,
@@ -65,7 +66,6 @@ from repro.runtime.executor import (BACKENDS, GraphExecutor, eval_node,
 from repro.runtime.graph import DISPATCHABLE_OPS, Graph, infer_types
 
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
-_DEFAULT_CACHE = "~/.cache/repro/autotune.json"
 
 # Default candidates: the pure-XLA formulations everywhere; the Pallas
 # kernels only compete where they are compiled (on TPU) — in interpret mode
@@ -83,7 +83,7 @@ def cache_path() -> pathlib.Path | None:
         return None
     if val:
         return pathlib.Path(val).expanduser()
-    return pathlib.Path(_DEFAULT_CACHE).expanduser()
+    return AUTOTUNE_FILE
 
 
 def _env_stamp() -> dict:
@@ -151,28 +151,47 @@ def _out_rows(node, in_shape: tuple[int, ...]) -> int:
     return max(oh, 1)
 
 
+def _direct_fits(backend: str, node, in_shape: tuple[int, ...],
+                 tile: dict) -> bool:
+    """Whether a direct-kernel tile's VMEM footprint fits the kernel's
+    budget (the chain kernel's accounting, :mod:`repro.kernels.chain_conv`)."""
+    from repro.kernels.chain_conv import plan_tile
+    from repro.kernels.direct_conv_bn_binarize import direct_stages
+
+    a = node.attrs
+    pool = backend == "vpu_direct_pool" and "pool_window" in a
+    stages = direct_stages(
+        a["kernel"], a["stride"], a["pad"], a["channels"],
+        a["pool_window"] if pool else None,
+        a.get("pool_stride"), tuple(a.get("pool_pad", (0, 0))))
+    return plan_tile(stages, in_shape, tile.get("block_h"),
+                     tile.get("block_w"), tile.get("block_n", 1)).fits()
+
+
 def _tile_candidates(backend: str, node,
                      in_shape: tuple[int, ...]) -> list[dict]:
     """Shape-derived (block_h, block_w, block_n) sweep for the direct
     kernels; the im2col backends have no per-node tile knobs here.
     Candidates are expressed in *effective* (clamped) tile sizes and
-    deduplicated so no configuration is compiled or timed twice."""
+    deduplicated so no configuration is compiled or timed twice.  Every
+    candidate is legal on the TPU: a split width is a multiple of 8 (the
+    sublane tiling), and the footprint fits the VMEM budget."""
     if backend not in ("vpu_direct", "vpu_direct_pool"):
         return [{}]
     n, fh = in_shape[0], _out_rows(node, in_shape)
-    default_bh = min(8, fh)                        # the kernel's default
     cands: list[dict] = [{}]
-    seen = {default_bh}
-    for bh in (4, 16, fh):
+    seen: set[int] = set()
+    for bh in (4, 8, 16, fh):
         eff = min(bh, fh)
         if eff not in seen:
             seen.add(eff)
             cands.append({"block_h": eff})
     if fh > 8:
-        cands.append({"block_h": default_bh, "block_w": 8})
+        cands.append({"block_h": min(8, fh), "block_w": 8})
     if n > 1:
         cands.append({"block_n": n})
-    return cands
+    return [t for t in cands
+            if not t or _direct_fits(backend, node, in_shape, t)]
 
 
 def _label(backend: str, tile: dict) -> str:
@@ -206,11 +225,11 @@ def _chain_tile_candidates(chain) -> list[dict]:
     """Per-chain tile sweep.  Chain tiles couple the stages through halo
     growth (a smaller final tile shrinks every interior tile but raises
     the recompute overlap fraction), so the sweep is over the *final*
-    tile: whole-map (no recompute — the default), a few spatial splits,
-    and a batch-spanning tile.  Candidates whose VMEM plan no longer fits
-    the chain's budget are dropped before timing."""
+    tile: the tallest band that fits (the default; the whole map when
+    it fits, which needs no recompute), a few spatial splits, and a
+    batch-spanning tile.  Candidates whose VMEM plan no longer fits the
+    chain's budget are dropped before timing."""
     from repro.kernels.chain_conv import chain_geometry
-    from repro.runtime.regions import plan_chain_vmem
 
     n, h, w = chain.in_shape[0], chain.in_shape[1], chain.in_shape[2]
     fh = chain_geometry(chain.stages, h, w, None, None).final_hw[0]
@@ -223,9 +242,7 @@ def _chain_tile_candidates(chain) -> list[dict]:
             cands.append({"block_h": eff})
     if n > 1:
         cands.append({"block_n": n})
-    return [t for t in cands
-            if plan_chain_vmem(chain.stages, chain.in_shape, tile=t,
-                               budget=chain.plan.budget).fits()]
+    return [t for t in cands if chain.tiling(t).vmem.fits()]
 
 
 class Autotuner:
@@ -403,10 +420,9 @@ class Autotuner:
     def _time_chain(self, chain, stage_arrays, x, tile: dict) -> float:
         from repro.kernels import ops as kops
 
-        offs, words = chain.arena(tile)
+        kw = chain.tiling(tile).kernel_args()
         fn = jax.jit(lambda arrs, xx: kops.chain_forward(
-            xx, chain.stages, arrs, arena_offsets=offs, arena_words=words,
-            **tile))
+            xx, chain.stages, arrs, **kw))
         for _ in range(self.warmup):
             jax.block_until_ready(fn(stage_arrays, x))
         times = []
@@ -440,8 +456,6 @@ class Autotuner:
         Winners cache/persist under chain-shaped ``chain::`` signatures —
         structurally identical regions across graphs or restarts reuse
         the measurement, exactly like per-node winners."""
-        from repro.runtime.regions import plan_chain_vmem
-
         fresh: dict[str, dict] = {}
         for chain in chains:
             key = _chain_signature(chain)
@@ -465,10 +479,7 @@ class Autotuner:
             # chain's: re-check, and degrade to the default tile (which
             # region formation already proved fits) rather than compile
             # an over-budget arena.
-            if tile and not plan_chain_vmem(chain.stages, chain.in_shape,
-                                            tile=tile,
-                                            budget=chain.plan.budget
-                                            ).fits():
+            if tile and not chain.tiling(tile).vmem.fits():
                 tile = {}
             chain.tile = tile
         self._save_disk(fresh)

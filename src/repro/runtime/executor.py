@@ -9,9 +9,9 @@ Per-node backends:
 * ``"xla"``             pure-JAX xor+popcount (paper Eqn 1; always available),
 * ``"xla_pm1"``         pure-JAX ±1-matmul reformulation (XLA maps it to the
                         platform matmul engine),
-* ``"mxu_pm1"``         ±1-matmul routed for the TPU MXU (same numerics as
-                        ``xla_pm1``; distinct name so autotune/benchmarks can
-                        report the intended engine),
+* ``"mxu_pm1"``         the ±1-matmul Pallas kernel on the MXU over im2col
+                        patches (the weighted first layer keeps the pure-JAX
+                        counts),
 * ``"vpu_popcount"``    the fused im2col Pallas kernel (interpret off-TPU),
 * ``"vpu_direct"``      the direct (im2col-free) Pallas kernel — conv ops
                         only (DESIGN.md §5),
@@ -232,10 +232,16 @@ class GraphExecutor:
                  backends: str | Mapping[int, str] = "xla",
                  tile_configs: Mapping[int, Mapping[str, int]] | None = None,
                  donate_input: bool = False,
-                 regions: Sequence[Any] | None = None):
+                 regions: Sequence[Any] | None = None,
+                 mesh=None, data_axis: str = "data"):
         graph.validate()
         self.graph = graph
         self.donate_input = donate_input
+        # Data-parallel execution: the whole graph runs per shard of the
+        # batch under ``shard_map`` over ``mesh[data_axis]`` — Pallas
+        # kernels cannot be partitioned automatically, so each device
+        # runs the program on its own rows (params replicated).
+        self.mesh, self.data_axis = mesh, data_axis
         # Fused regions (runtime.regions.Chain): each is evaluated whole by
         # the chain megakernel when the schedule reaches its head; member
         # nodes are skipped and the result binds to the tail's id.
@@ -271,15 +277,22 @@ class GraphExecutor:
         self._schedule = graph.topo_order()
         self.trace_count = 0
         self._node_jits: dict[int, Any] = {}  # traced_call's own cache
+        run = self._run
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
+
+            run = jax.shard_map(self._run, mesh=mesh,
+                                in_specs=(P(), P(data_axis)),
+                                out_specs=P(data_axis), check_vma=False)
         if donate_input:
             # The serving path hands each batch's input buffer to the
             # device for reuse (arg 1 = x; arg 0, the params, is never
             # donated).  Off-TPU XLA declines uint8 donations with a
             # warning — donation is permission, not a requirement.
             _ignore_donation_warning()
-            self._jitted = jax.jit(self._run, donate_argnums=(1,))
+            self._jitted = jax.jit(run, donate_argnums=(1,))
         else:
-            self._jitted = jax.jit(self._run)
+            self._jitted = jax.jit(run)
 
     # ---- execution -------------------------------------------------------
     def _run(self, arrays, x):
@@ -399,7 +412,15 @@ class GraphExecutor:
                       | None = None) -> "GraphExecutor":
         return GraphExecutor(self.graph, backends, tile_configs,
                              donate_input=self.donate_input,
-                             regions=self.regions)
+                             regions=self.regions, mesh=self.mesh,
+                             data_axis=self.data_axis)
+
+    def sharded(self, mesh, data_axis: str = "data") -> "GraphExecutor":
+        """This executor run data-parallel over ``mesh[data_axis]``."""
+        return GraphExecutor(self.graph, self.backends, self.tile_configs,
+                             donate_input=self.donate_input,
+                             regions=self.regions, mesh=mesh,
+                             data_axis=data_axis)
 
     def backend_report(self) -> list[dict]:
         rows = []

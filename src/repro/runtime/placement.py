@@ -321,7 +321,7 @@ class StagedExecutor:
         forbidden: frozenset[int] = frozenset()
         if mode == "vpu_chain":
             budget = (vmem_budget if vmem_budget is not None
-                      else _regions.DEFAULT_VMEM_BUDGET)
+                      else _regions.VMEM_BUDGET)
             forbidden = chain_interiors(_regions.partition_chains(
                 graph, tuple(input_shape), vmem_budget=budget))
         self.plan = plan_pipeline(graph, input_shape, len(devices),

@@ -19,11 +19,14 @@ Region-formation rules (§9.1):
   consumer, the next member (fan-out forces a chain break — the branching
   value must be materialized);
 * the chain's VMEM plan must fit the budget
-  (:func:`~repro.runtime.memory.vmem_plan`): interior tile intermediates
-  under lifetime first-fit reuse, plus the fixed residents (entry tile,
-  weights, final tile, popcount accumulator).  A run that exceeds the
-  budget is split greedily — the longest fitting prefix becomes a region
-  and the cut boundary spills to HBM;
+  (:func:`~repro.runtime.memory.vmem_plan`) at a final tile of at least
+  :data:`MIN_TILE_ROWS` rows: interior tile intermediates under lifetime
+  first-fit reuse, plus the fixed residents (entry tile, weights and
+  final tile, each double-buffered, and the conv row temporaries), all in
+  the TPU's padded (8, 128) tiling
+  (:func:`~repro.kernels.chain_conv.vmem_footprint`).  A run that exceeds
+  the budget is split greedily — the longest fitting prefix becomes a
+  region and the cut boundary spills to HBM;
 * runs shorter than ``min_nodes`` (default 2) stay on the per-node path.
 
 Chains that fail any rule simply do not form; the executor evaluates
@@ -38,15 +41,15 @@ from typing import Mapping, Sequence
 
 import jax.numpy as jnp
 
-from repro.core.packing import num_words
-from repro.kernels.chain_conv import (StageSpec, chain_geometry,
-                                      chain_word_counts)
+from repro.kernels.chain_conv import (VMEM_BUDGET, StageSpec,
+                                      chain_word_counts, plan_tile)
 from repro.runtime.graph import (PACKED_OPS, Graph, TensorType, infer_types)
 from repro.runtime.memory import VmemPlan, vmem_plan
 
-# Per-core VMEM is ~16 MiB on current TPUs; default to half so the chain
-# arena coexists with Pallas' double-buffered entry/exit blocks.
-DEFAULT_VMEM_BUDGET = 8 * 2 ** 20
+# A region must fit at a final tile of at least this many rows (or the
+# whole map): thinner bands make the halo recompute dominate, so the run
+# splits instead.
+MIN_TILE_ROWS = 8
 
 CHAIN_OPS = frozenset({"packed_conv", "packed_conv_pool", "or_pool",
                        "maxpool_pm1"})
@@ -97,15 +100,14 @@ class Chain:
     def tail(self) -> int:
         return self.node_ids[-1]
 
-    def arena(self, tile: Mapping[str, int] | None = None
-              ) -> tuple[tuple[int, ...], int]:
-        """(int32-element offsets per interior stage output, arena words)
-        for a concrete tile config — recomputed because tile shape changes
-        the interior sizes the planner packs."""
-        plan = plan_chain_vmem(self.stages, self.in_shape,
-                               tile=dict(tile if tile is not None
-                                         else self.tile))
-        return (tuple(o // 4 for o in plan.offsets), plan.arena_bytes // 4)
+    def tiling(self, tile: Mapping[str, int] | None = None
+               ) -> ChainTiling:
+        """A tile config (default: this chain's own) resolved under this
+        chain's budget — recomputed because tile shape changes the
+        interior sizes the planner packs."""
+        return plan_chain(self.stages, self.in_shape,
+                          self.tile if tile is None else tile,
+                          self.plan.budget)
 
     def hbm_bytes_avoided(self) -> int:
         """Whole-map HBM traffic the fusion removes: one store + one load
@@ -136,41 +138,44 @@ def stages_hbm_bytes_avoided(stages: Sequence[StageSpec],
     return total
 
 
-def plan_chain_vmem(stages: Sequence[StageSpec], in_shape: Sequence[int],
-                    *, tile: Mapping[str, int] | None = None,
-                    budget: int | None = None) -> VmemPlan:
-    """The VMEM plan for one chain at one tile config: interior stage
-    tiles (lifetime [k, k+1]) go through the planner's first-fit; the
-    fixed residents (entry tile, conv weights, final tile, widest popcount
-    accumulator) are summed into ``fixed_bytes`` for the budget check."""
-    tile = dict(tile or {})
-    n, h, w, cw0 = in_shape
-    bn = max(1, min(tile.get("block_n", 1), n))
-    geo = chain_geometry(tuple(stages), h, w, tile.get("block_h"),
-                         tile.get("block_w"))
-    cws = chain_word_counts(tuple(stages), cw0)
+@dataclasses.dataclass(frozen=True)
+class ChainTiling:
+    """One chain tile config resolved: the kernel's tile arguments with
+    ``block_h`` explicit, the VMEM plan, and the planned arena's row
+    offsets (per interior stage output) and rows."""
+    tile: dict
+    vmem: VmemPlan
+    arena_offsets: tuple[int, ...]
+    arena_rows: int
 
-    sizes = [4 * bn * th * tw * cws[k + 1]
-             for k, (th, tw) in enumerate(geo.out_tile[:-1])]
-    fixed = 4 * bn * geo.entry_tile[0] * geo.entry_tile[1] * cw0
-    fh, fw = geo.out_tile[-1]
-    fixed += 4 * bn * fh * fw * cws[-1]
-    acc = 0
-    for k, st in enumerate(stages):
-        if st.kind != "conv":
-            continue
-        o_pad = num_words(st.channels) * 32
-        taps = st.kernel * st.kernel * cws[k]
-        fixed += 4 * (o_pad * taps + taps + 2 * o_pad)       # w, ww, t, s
-        th, tw = geo.out_tile[k]
-        acc = max(acc, 4 * bn * th * tw * o_pad)             # accumulator
-    return vmem_plan(sizes, budget=budget, fixed_bytes=fixed + acc)
+    def kernel_args(self) -> dict:
+        """Keyword arguments of :func:`~repro.kernels.ops.chain_forward`."""
+        return dict(self.tile, arena_offsets=self.arena_offsets,
+                    arena_rows=self.arena_rows)
+
+
+def plan_chain(stages: Sequence[StageSpec], in_shape: Sequence[int],
+               tile: Mapping[str, int] | None = None,
+               budget: int = VMEM_BUDGET) -> ChainTiling:
+    """Resolve a chain tile config (:func:`~repro.kernels.chain_conv.
+    plan_tile`: ``block_h`` unset means the tallest band that fits
+    ``budget``) and plan its VMEM: interior stage tiles (lifetime
+    [k, k+1]) go through the planner's first-fit in whole arena rows;
+    the fixed residents count toward the budget check."""
+    tile = dict(tile or {})
+    tp = plan_tile(stages, in_shape, tile.get("block_h"),
+                   tile.get("block_w"), tile.get("block_n", 1), budget)
+    vmem = vmem_plan(tp.sizes, budget=budget, fixed_bytes=tp.fixed)
+    tile["block_h"] = tp.block_h
+    return ChainTiling(tile, vmem,
+                       tuple(o // tp.row_bytes for o in vmem.offsets),
+                       vmem.arena_bytes // tp.row_bytes)
 
 
 def build_chain(graph: Graph, node_ids: Sequence[int],
                 input_shape: Sequence[int],
                 types: Mapping[int, TensorType] | None = None,
-                budget: int | None = None) -> Chain:
+                budget: int = VMEM_BUDGET) -> Chain:
     """Assemble a :class:`Chain` from explicit member ids (must be a valid
     path of chainable ops).  Public so tests can split chains at arbitrary
     boundaries."""
@@ -181,9 +186,20 @@ def build_chain(graph: Graph, node_ids: Sequence[int],
     for nid in node_ids:
         stages.extend(node_stages(graph.nodes[nid]))
     in_shape = types[graph.nodes[node_ids[0]].inputs[0]].shape
-    plan = plan_chain_vmem(stages, in_shape, budget=budget)
+    plan = plan_chain(stages, in_shape, budget=budget).vmem
     return Chain(node_ids=node_ids, stages=tuple(stages),
                  in_shape=in_shape, plan=plan)
+
+
+def _fits(chain: Chain) -> bool:
+    """Budget gate of region formation: the plan fits at a final tile of
+    at least :data:`MIN_TILE_ROWS` rows (or the whole map)."""
+    if not chain.plan.fits():
+        return False
+    rows = chain.in_shape[1]
+    for st in chain.stages:
+        rows = st.out_size(rows)
+    return chain.tiling({}).tile["block_h"] >= min(rows, MIN_TILE_ROWS)
 
 
 def _chainable(graph: Graph, nid: int) -> bool:
@@ -199,7 +215,7 @@ def _chainable(graph: Graph, nid: int) -> bool:
 
 
 def partition_chains(graph: Graph, input_shape: Sequence[int],
-                     *, vmem_budget: int | None = DEFAULT_VMEM_BUDGET,
+                     *, vmem_budget: int = VMEM_BUDGET,
                      min_nodes: int = 2,
                      types: Mapping[int, TensorType] | None = None
                      ) -> list[Chain]:
@@ -238,7 +254,7 @@ def partition_chains(graph: Graph, input_shape: Sequence[int],
             for end in range(start + 1, len(run) + 1):
                 cand = build_chain(graph, run[start:end], input_shape,
                                    types=types, budget=vmem_budget)
-                if not cand.plan.fits():
+                if not _fits(cand):
                     break
                 best = cand
             if best is None:          # even a single node busts the budget
@@ -273,14 +289,13 @@ def eval_chain(chain: Chain, params_by_node: Mapping[str, Mapping],
     :mod:`repro.kernels.ops` so interpret mode follows the platform)."""
     from repro.kernels import ops as kops
 
-    offsets, words = chain.arena(chain.tile)
     return kops.chain_forward(
         x, chain.stages, chain_stage_arrays(chain, params_by_node),
-        arena_offsets=offsets, arena_words=words, **chain.tile)
+        **chain.tiling().kernel_args())
 
 
 def chain_executor(graph: Graph, input_shape: Sequence[int],
-                   *, vmem_budget: int | None = DEFAULT_VMEM_BUDGET,
+                   *, vmem_budget: int = VMEM_BUDGET,
                    tuner=None, donate_input: bool = False):
     """Build the region-fused executor: partition the schedule into
     budget-fitting chains, optionally sweep per-chain tile shapes with an

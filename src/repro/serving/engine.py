@@ -149,7 +149,8 @@ class PhoneBitEngine:
 
     def compile(self, batch_size: int | None = None, *,
                 donate_input: bool = False, data_parallel: int = 1,
-                mode: str | None = None, pipeline=None):
+                mode: str | None = None, pipeline=None, mesh=None,
+                data_axis: str = "data"):
         """Build (once) the executable for one serving bucket.
 
         Returns the cached :class:`GraphExecutor` for
@@ -174,6 +175,11 @@ class PhoneBitEngine:
         per device.  Mutually exclusive with ``data_parallel > 1``
         (compose data-parallel *replicas of pipelines* via
         :class:`~repro.distributed.replicas.ReplicaGroup` instead).
+
+        ``data_parallel > 1`` runs the bucket per shard of the batch
+        under ``shard_map`` over ``mesh[data_axis]`` (default: a 1-D mesh
+        of the first ``data_parallel`` devices) — Pallas kernels are not
+        partitioned automatically.
         """
         from repro import runtime
 
@@ -229,6 +235,12 @@ class PhoneBitEngine:
                 else:
                     exe = runtime.GraphExecutor(self._graph, mode,
                                                 donate_input=donate_input)
+                if data_parallel > 1:
+                    if mesh is None:
+                        mesh = jax.make_mesh(
+                            (data_parallel,), (data_axis,),
+                            devices=jax.devices()[:data_parallel])
+                    exe = exe.sharded(mesh, data_axis)
             self._record_compile_metrics(exe, bs, data_parallel)
             self._compiled[key] = exe
         return self._compiled[key]

@@ -280,6 +280,8 @@ class InferenceServer:
         kw = {}
         if self.pipeline_devices is not None:
             kw["pipeline"] = self.pipeline_devices
+        if self.data_parallel > 1:
+            kw.update(mesh=self.mesh, data_axis=self.data_axis)
         return self.engine.compile(bucket, donate_input=self.donate_input,
                                    data_parallel=self.data_parallel,
                                    mode=mode, **kw)

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import bnn_model
 from repro.core.bnn_model import BConv, BDense, FloatConv, FloatDense, Pool
 from repro.models import paper_nets
 from repro.serving import InferenceServer, PhoneBitEngine
@@ -50,20 +49,44 @@ from repro.workloads.postprocess import DetectConfig
 def checkpoint_params(spec, seed: int = 0) -> list[dict]:
     """The seeded golden checkpoint: deterministic latent-float params.
 
-    ``init_params`` with a seeded key, then BN statistics drawn from a
-    seeded numpy generator (identity BN would make half the integer
-    thresholds degenerate — randomized BN is what the golden fixtures and
-    conformance sweeps need to exercise the threshold math).
+    Drawn from one numpy generator seeded by ``seed`` — the distributions
+    ``bnn_model.init_params`` uses (uniform(-1, 1) binary latents, scaled
+    normal float layers), but independent of JAX's PRNG implementation
+    and its defaults, so the checkpoint (and every golden fixture built
+    on it) is the same under any JAX version.  A layer type added to
+    ``init_params`` needs its draw here too.  BN statistics are
+    randomized too: identity BN would make half the integer thresholds
+    degenerate, and the golden fixtures and conformance sweeps need to
+    exercise the threshold math.
     """
-    params = bnn_model.init_params(jax.random.key(seed), spec)
     rng = np.random.default_rng(seed)
-    for p in params:
-        if "mu" in p:
-            o = p["mu"].shape[0]
-            p["mu"] = jnp.asarray(rng.uniform(-20, 20, o), jnp.float32)
-            p["var"] = jnp.asarray(rng.uniform(0.5, 4, o), jnp.float32)
-            p["gamma"] = jnp.asarray(rng.uniform(-1.5, 1.5, o), jnp.float32)
-            p["beta"] = jnp.asarray(rng.uniform(-1, 1, o), jnp.float32)
+
+    def arr(x):
+        return jnp.asarray(x, jnp.float32)
+
+    params: list[dict] = []
+    for layer in spec:
+        if isinstance(layer, (BConv, BDense)):
+            shape = ((layer.kernel, layer.kernel, layer.c_in, layer.c_out)
+                     if isinstance(layer, BConv)
+                     else (layer.d_in, layer.d_out))
+            o = shape[-1]
+            params.append(dict(
+                w=arr(rng.uniform(-1.0, 1.0, shape)),
+                gamma=arr(rng.uniform(-1.5, 1.5, o)),
+                beta=arr(rng.uniform(-1, 1, o)),
+                mu=arr(rng.uniform(-20, 20, o)),
+                var=arr(rng.uniform(0.5, 4, o))))
+        elif isinstance(layer, (FloatConv, FloatDense)):
+            shape = ((layer.kernel, layer.kernel, layer.c_in, layer.c_out)
+                     if isinstance(layer, FloatConv)
+                     else (layer.d_in, layer.d_out))
+            fan = int(np.prod(shape[:-1]))
+            params.append(dict(
+                w=arr(rng.standard_normal(shape) / np.sqrt(fan)),
+                b=arr(np.zeros(shape[-1]))))
+        else:
+            params.append({})
     return params
 
 
@@ -96,7 +119,7 @@ class WorkloadEngine:
     # ---- engine surface (what InferenceServer consumes) ------------------
     def compile(self, batch_size: int | None = None, *,
                 donate_input: bool = False, data_parallel: int = 1,
-                mode: str | None = None):
+                mode: str | None = None, **kw):
         # Resolved-mode key (like PhoneBitEngine's): the server's health
         # ladder passes the concrete mode string, direct calls pass None
         # — both must hit the same cached (or artifact-loaded) entry.
@@ -104,7 +127,8 @@ class WorkloadEngine:
                mode or self.matmul_mode)
         if key not in self._compiled:
             fwd = self.engine.compile(batch_size, donate_input=donate_input,
-                                      data_parallel=data_parallel, mode=mode)
+                                      data_parallel=data_parallel, mode=mode,
+                                      **kw)
             self._compiled[key] = \
                 lambda x, fwd=fwd: self._head_jit(fwd(x))
         return self._compiled[key]

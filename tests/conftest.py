@@ -1,11 +1,8 @@
-"""Test bootstrap: src/ on sys.path + a hypothesis fallback.
+"""Test bootstrap: src/ on sys.path and a hermetic autotune cache.
 
 Keeps `python -m pytest` working from the repo root even without an
 installed package (pyproject's `pythonpath = ["src"]` does the same for
-pytest >= 7; this also covers direct module imports).  When the real
-``hypothesis`` package is unavailable in the environment, installs the
-deterministic stub from ``tests/_hypothesis_stub.py`` so the
-property-based modules still collect and run.
+pytest >= 7; this also covers direct module imports).
 """
 
 import os
@@ -18,21 +15,12 @@ if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
 # Keep the autotuner's disk cache hermetic: never read/write the real
-# ~/.cache/repro/autotune.json from the test suite (individual tests
+# <repo>/.cache/repro/autotune.json from the test suite (individual tests
 # override this per-case via monkeypatch).
 os.environ.setdefault(
     "REPRO_AUTOTUNE_CACHE",
     os.path.join(tempfile.mkdtemp(prefix="repro-autotune-"),
                  "autotune.json"))
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
-    import _hypothesis_stub
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
 
 
 def pytest_addoption(parser):

@@ -12,7 +12,8 @@ from repro.core import binary_conv, bnn_model, converter, layer_integration, \
 from repro.core.bnn_model import BConv, BDense, FloatDense, Pool
 from repro import runtime
 from repro.kernels import ops as kops
-from repro.kernels.chain_conv import StageSpec, chain_geometry
+from repro.kernels.chain_conv import (StageSpec, chain_conv, chain_geometry,
+                                      plan_tile)
 from repro.runtime import (Autotuner, GraphExecutor, build_chain,
                            chain_executor, lower_packed, partition_chains,
                            plan_memory, vmem_plan)
@@ -113,18 +114,31 @@ class TestChainKernel:
         rng = np.random.default_rng(5)
         wp3, p3 = _conv_pair(rng, 64, 32, 1)
         arrays = arrays + (wp3, None, p3.threshold, p3.sign_flip)
-        plan = regions_mod.plan_chain_vmem(stages, xp.shape)
+        tiling = regions_mod.plan_chain(stages, xp.shape)
+        plan = tiling.vmem
         # three interior buffers with lifetimes [k, k+1]: first and third
         # must share space, so the planned arena beats the no-reuse sum
         assert len(plan.offsets) == 3
         assert plan.arena_bytes < plan.naive_bytes()
         assert plan.offsets[0] == plan.offsets[2]
-        got = kops.chain_forward(
-            xp, stages, arrays,
-            arena_offsets=tuple(o // 4 for o in plan.offsets),
-            arena_words=plan.arena_bytes // 4)
+        assert tiling.arena_offsets[0] == tiling.arena_offsets[2]
+        got = kops.chain_forward(xp, stages, arrays, **tiling.kernel_args())
         want = kops.chain_forward(xp, stages, arrays)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_default_tile_over_budget_raises(self):
+        """When not even one output row fits the VMEM budget, the kernel
+        names the budget instead of handing Mosaic a tile it cannot
+        place (traced only: no array of this size is made)."""
+        stages = (StageSpec("conv", 3, 1, 1, 1, channels=32),)
+        shape = (1, 8, 1 << 16, 128)
+        assert not plan_tile(stages, shape).fits()
+        sds = jax.ShapeDtypeStruct
+        with pytest.raises(ValueError, match="VMEM_BUDGET"):
+            jax.eval_shape(
+                lambda x, w, t, s: chain_conv(x, stages, (w, None, t, s)),
+                sds(shape, jnp.int32), sds((32, 9 * 128), jnp.int32),
+                sds((32,), jnp.int32), sds((32,), jnp.int32))
 
     def test_geometry_halo_growth(self):
         """Entry tile = final tile grown through every window and stride."""
@@ -336,9 +350,9 @@ class TestChainAutotune:
         cands = _chain_tile_candidates(chain)
         assert {} in cands and len(cands) >= 2
         for tile in cands:
-            assert regions_mod.plan_chain_vmem(
-                chain.stages, chain.in_shape, tile=tile,
-                budget=chain.plan.budget).fits()
+            assert regions_mod.plan_chain(
+                chain.stages, chain.in_shape, tile,
+                chain.plan.budget).vmem.fits()
 
 
 # --------------------------------------------------------------------------

@@ -49,7 +49,7 @@ class TestDirectConvKernel:
         (7, 64, 64, 1, 1, 0, {}),                        # 1x1 pointwise
         (11, 64, 32, 3, 1, 1, dict(block_h=3, block_w=4)),  # non-multiple
         (8, 33, 32, 3, 1, 1, dict(block_n=2)),           # ragged Cw + batch
-        (8, 64, 32, 5, 2, 2, dict(block_o=32)),          # k5 s2 p2
+        (8, 64, 32, 5, 2, 2, dict(block_h=2)),           # k5 s2 p2
     ])
     def test_vs_float_oracle_and_im2col(self, h, c_in, c_out, kh, stride,
                                         pad, block_kw):

@@ -496,7 +496,7 @@ def test_data_parallel_placement_matches_mesh_path():
 
 _LM_PRELUDE = """
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import _mesh  # AxisType version-compat
+from repro.launch.mesh import _mesh
 mesh = _mesh((2, 4), ("data", "model"))
 from repro.distributed.sharding import rules_for_mesh
 rules = rules_for_mesh(mesh)

@@ -435,8 +435,11 @@ class TestEngineGraphPath:
             np.testing.assert_array_equal(got, np.asarray(flat_ref))
             np.testing.assert_allclose(got, np.asarray(float_ref),
                                        rtol=0, atol=1e-3)
+        # Same batch as the reference: XLA's float head may round the
+        # last ulp differently at another batch size.
         got = np.asarray(GraphExecutor(g, "vpu_popcount")(x[:1]))
-        np.testing.assert_array_equal(got, np.asarray(flat_ref)[:1])
+        np.testing.assert_array_equal(
+            got, np.asarray(bnn_model.packed_forward(packed, spec, x[:1])))
 
     def test_core_to_graph_hooks(self, tiny):
         spec, params, packed, x = tiny
@@ -503,11 +506,14 @@ class TestDifferentialFuzz:
             np.testing.assert_array_equal(
                 np.asarray(GraphExecutor(g, backend)(x)), ref,
                 err_msg=f"{backend} diverges on spec {spec}")
-        # interpret-mode Pallas backends: batch 1 keeps them fast
+        # interpret-mode Pallas backends: batch 1 keeps them fast; the
+        # reference runs at the same batch (XLA's float head may round
+        # the last ulp differently at another batch size)
+        ref1 = np.asarray(GraphExecutor(g, "xla")(x[:1]))
         for backend in ("vpu_popcount", "vpu_direct", "vpu_direct_pool"):
             got = np.asarray(GraphExecutor(g, backend)(x[:1]))
             np.testing.assert_array_equal(
-                got, ref[:1], err_msg=f"{backend} diverges on spec {spec}")
+                got, ref1, err_msg=f"{backend} diverges on spec {spec}")
 
     @given(st.integers(0, 10**9))
     @settings(max_examples=4, deadline=None)
@@ -561,12 +567,6 @@ os.environ["REPRO_AUTOTUNE_CACHE"] = "0"
 import sys
 sys.path.insert(0, {src!r})
 sys.path.insert(0, {tests!r})
-try:
-    import hypothesis  # noqa: F401  (stub keeps the import below legal)
-except ImportError:
-    import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
 import jax, jax.numpy as jnp
 import numpy as np
 from test_graph_runtime import _random_spec, _randomize_bn
