@@ -94,7 +94,7 @@ class ReplicaGroup:
     def __init__(self, engine, devices: Sequence[Any], *,
                  devices_per_replica: int = 1,
                  names: Sequence[str] | None = None,
-                 clock: Callable[[], float] = time.monotonic,
+                 clock: Callable[[], float] = _trace.clock,
                  sleep: Callable[[float], None] | None = None,
                  slow_after: int = 3,
                  **server_kw):
@@ -300,7 +300,7 @@ class LMReplicaGroup:
 
     def __init__(self, cfg, rules, params, *, n_slots: int, max_seq: int,
                  n_lanes: int = 2, names: Sequence[str] | None = None,
-                 clock: Callable[[], float] = time.monotonic,
+                 clock: Callable[[], float] = _trace.clock,
                  probe_after_s: float = 30.0, probe_backoff: float = 2.0,
                  **lane_kw):
         from repro.serving.lm_server import LMServer

@@ -63,6 +63,7 @@ def bitplane_pack(x: jnp.ndarray, *, block_h: int = 8,
         out_shape=jax.ShapeDtypeStruct((n, gh * bh, w, NUM_PLANES * cw),
                                        jnp.int32),
         interpret=interpret,
+        name="bitplane_pack",
         compiler_params=compiler_params(("parallel", "parallel")),
     )(x)
     return out[:, :h]

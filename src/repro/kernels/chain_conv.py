@@ -334,14 +334,15 @@ def _kernel(*refs, stages: tuple[StageSpec, ...], geo: _Geometry,
 @functools.partial(
     jax.jit,
     static_argnames=("stages", "block_h", "block_w", "block_n",
-                     "arena_offsets", "arena_rows", "interpret"))
+                     "arena_offsets", "arena_rows", "interpret", "name"))
 def chain_conv(x_packed: jnp.ndarray, stages: tuple[StageSpec, ...],
                stage_arrays: tuple[jnp.ndarray, ...],
                *, block_h: int | None = None, block_w: int | None = None,
                block_n: int = 1,
                arena_offsets: tuple[int, ...] | None = None,
                arena_rows: int | None = None,
-               interpret: bool = False) -> jnp.ndarray:
+               interpret: bool = False,
+               name: str = "chain_region") -> jnp.ndarray:
     """Run a static conv/pool chain in one Pallas call.
 
     x_packed: (N, H, W, Cw) int32 packed words (bit-plane words for a
@@ -357,6 +358,9 @@ def chain_conv(x_packed: jnp.ndarray, stages: tuple[StageSpec, ...],
         and total arena rows, normally from the memory planner's
         :func:`~repro.runtime.memory.vmem_plan`; defaulted to a dense
         no-reuse layout when omitted (kernel-level tests).
+    name: the kernel's name on the device (its HLO instruction):
+        ``chain_region`` for a fused region, ``direct_conv`` /
+        ``direct_conv_pool`` for one node on the direct backends.
     Returns (N, FH, FW, ceil(O_last/32)) int32 (pool chains keep Cw).
     """
     n, h, w_in, cw0 = x_packed.shape
@@ -436,5 +440,6 @@ def chain_conv(x_packed: jnp.ndarray, stages: tuple[StageSpec, ...],
             (max(arena_rows, 1),) + arena_shape(geo, cws), jnp.int32)],
         interpret=interpret,
         compiler_params=compiler_params(("parallel",) * 3),
+        name=name,
     )(x_packed, *ops)
     return out[:n, :fh, :fw, :]

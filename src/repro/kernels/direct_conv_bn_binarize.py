@@ -73,4 +73,6 @@ def direct_conv_bn_binarize(
     return chain_conv(x_packed, stages,
                       (w_packed, word_weights, threshold, sign_flip),
                       block_h=block_h, block_w=block_w, block_n=block_n,
-                      interpret=interpret)
+                      interpret=interpret,
+                      name=("direct_conv" if pool_window is None
+                            else "direct_conv_pool"))

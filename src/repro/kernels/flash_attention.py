@@ -128,6 +128,7 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
         out_specs=pl.BlockSpec((None, block_q, hd), lambda r, i: (r, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     out = out.reshape(b, kvh, g, sq, hd)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, sq, h, hd)

@@ -147,6 +147,7 @@ def fused_matmul_bn_binarize(a: jnp.ndarray, b: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * nw), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="matmul_bn_binarize",
         compiler_params=compiler_params(),
     )(a, b.T, word_weights, threshold, sign_flip)
     return out[:m, : -(-n // WORD_BITS)]

@@ -86,6 +86,7 @@ def mxu_pm1_matmul(a: jnp.ndarray, b: jnp.ndarray, *, k_valid: int,
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="pm1_matmul",
         compiler_params=compiler_params(),
     )(a, b)
     pad_bits = gk * bk * WORD_BITS - k_valid

@@ -155,6 +155,7 @@ def xnor_popcount_matmul(a: jnp.ndarray, b: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="xnor_matmul",
         compiler_params=compiler_params(),
     )(a, b.T, word_weights)
     return out[:m, :n]

@@ -20,9 +20,10 @@ from __future__ import annotations
 
 import contextlib
 import math
-import time
 from collections import deque
 from typing import Callable, Iterable, Sequence
+
+from repro.obs.trace import clock as _clock
 
 
 # ---- canonical percentile / summary math ----------------------------------
@@ -191,7 +192,7 @@ class ServingMetrics:
     process registry keeps the runtime-wide series (autotune, retraces,
     arena bytes) that *are* shared."""
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic,
+    def __init__(self, clock: Callable[[], float] = _clock,
                  registry: MetricsRegistry | None = None,
                  prefix: str = "serve"):
         self._clock = clock

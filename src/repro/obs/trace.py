@@ -24,15 +24,22 @@ with ``ts``/``dur`` in microseconds, ``ph: "i"`` instants), loadable in
 check shared by the tests, the example, and CI's obs-smoke job.
 
 Span taxonomy (full table in DESIGN.md §10.1): ``serve.*`` for the
-request path, ``node.*``/``region.*`` for per-node executor execution,
-``compile.*`` for bucket compilation, ``autotune.*`` for sweeps.
+request path, ``compile.*`` for bucket compilation, ``autotune.*`` for
+sweeps.  Per-node device work is not a host span: the executor traces
+each node and region under a ``jax.named_scope`` (``n<id>.<op>``,
+``region.<ids>``), which the compiled ops carry in their metadata
+(:mod:`repro.obs.scopes`).
+
+:data:`clock` is the one program clock: spans, the servers' flight
+stamps and their default clocks all read it.
 
 ``Tracer(annotate_jax=True)`` additionally enters a
 ``jax.profiler.TraceAnnotation`` per span so host spans line up with
-device events when a ``jax.profiler`` session is active;
-:meth:`Tracer.start_jax_profiler` / :meth:`Tracer.stop_jax_profiler`
-manage such a session (best-effort — absent profiler support is not an
-error).
+device events when a ``jax.profiler`` session is active.  Its first span
+also writes one ``obs.clock`` annotation and keeps the clock reading
+taken inside it (``anchor_s``): a program time ``t`` then lies at
+``(t - anchor_s)`` seconds from that annotation on the profiler's
+timeline.
 """
 
 from __future__ import annotations
@@ -41,6 +48,12 @@ import json
 import threading
 import time
 from typing import Any, Callable
+
+#: The program clock (seconds): flight stamps, spans and the servers'
+#: default clocks read this one function, so their stamps compare.
+clock = time.perf_counter
+#: Name of the annotation that ties :data:`clock` to a profiler trace.
+CLOCK_ANCHOR = "obs.clock"
 
 
 class _NullSpan:
@@ -127,6 +140,8 @@ class Span:
             try:
                 import jax
 
+                if self._tracer.anchor_s is None:
+                    self._tracer.mark_clock()
                 self._ann = jax.profiler.TraceAnnotation(self.name)
                 self._ann.__enter__()
             except Exception:
@@ -152,7 +167,7 @@ class Tracer:
     bounded captures).
     """
 
-    def __init__(self, clock: Callable[[], float] = time.perf_counter,
+    def __init__(self, clock: Callable[[], float] = clock,
                  max_events: int = 200_000, annotate_jax: bool = False,
                  pid: int = 0):
         self.clock = clock
@@ -162,6 +177,8 @@ class Tracer:
         self.events: list[dict] = []
         self.dropped_events = 0
         self._epoch = clock()
+        # clock reading inside the ``obs.clock`` annotation (annotate_jax)
+        self.anchor_s: float | None = None
 
     # ---- recording --------------------------------------------------------
     def span(self, name: str, kind: str = "host", **attrs) -> Span:
@@ -195,25 +212,15 @@ class Tracer:
         return [e for e in self.events
                 if e["ph"] == "X" and e["name"].startswith(prefix)]
 
-    # ---- jax.profiler session (optional) ----------------------------------
-    def start_jax_profiler(self, logdir: str) -> bool:
-        """Start a ``jax.profiler`` trace session alongside host spans
-        (best-effort; returns whether it started)."""
-        try:
-            import jax
+    # ---- profiler clock anchor --------------------------------------------
+    def mark_clock(self) -> float:
+        """Write the ``obs.clock`` annotation into the active profiler
+        session and keep the clock reading taken inside it."""
+        import jax
 
-            jax.profiler.start_trace(logdir)
-            return True
-        except Exception:
-            return False
-
-    def stop_jax_profiler(self) -> None:
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        with jax.profiler.TraceAnnotation(CLOCK_ANCHOR):
+            self.anchor_s = self.clock()
+        return self.anchor_s
 
     # ---- export -----------------------------------------------------------
     def to_chrome(self, meta: dict | None = None) -> dict:

@@ -50,6 +50,7 @@ from repro.core import (binary_conv, binary_ops, bitplanes,
                         layer_integration, packing)
 from repro.core.bnn_model import _BN_EPS
 from repro.obs import metrics as _obs_metrics
+from repro.obs import scopes as _scopes
 from repro.obs import trace as _trace
 from repro.runtime.graph import DISPATCHABLE_OPS, Graph
 from repro.serving import faults as _faults
@@ -276,7 +277,7 @@ class GraphExecutor:
                        for nid, n in graph.nodes.items() if n.params}
         self._schedule = graph.topo_order()
         self.trace_count = 0
-        self._node_jits: dict[int, Any] = {}  # traced_call's own cache
+        self._op_scopes: dict[tuple, dict] = {}
         run = self._run
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -303,6 +304,10 @@ class GraphExecutor:
         _obs_metrics.get_registry().counter("runtime.retraces").inc()
         g = self.graph
         env: dict[int, Any] = {}
+        # Every node and region is traced under its own named scope: the
+        # compiled ops carry it in their metadata, which is how device
+        # time is charged to nodes (``op_scopes``).  Names only: the
+        # computation and its results are unchanged.
         for nid in self._schedule:
             node = g.nodes[nid]
             if node.op == "input":
@@ -313,14 +318,17 @@ class GraphExecutor:
                     from repro.runtime import regions as _regions
 
                     chain = self._region_head[nid]
-                    env[chain.tail] = _regions.eval_chain(
-                        chain, arrays, env[node.inputs[0]])
+                    label = "+".join(map(str, chain.node_ids))
+                    with jax.named_scope(f"region.{label}"):
+                        env[chain.tail] = _regions.eval_chain(
+                            chain, arrays, env[node.inputs[0]])
                 continue
-            env[nid] = eval_node(
-                node.op, node.attrs, arrays.get(str(nid), {}),
-                [env[i] for i in node.inputs],
-                backend=self.backends.get(nid, "xla"),
-                tile=self.tile_configs.get(nid))
+            with jax.named_scope(f"n{nid}.{node.op}"):
+                env[nid] = eval_node(
+                    node.op, node.attrs, arrays.get(str(nid), {}),
+                    [env[i] for i in node.inputs],
+                    backend=self.backends.get(nid, "xla"),
+                    tile=self.tile_configs.get(nid))
         return env[g.output_id]
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -338,73 +346,40 @@ class GraphExecutor:
                          regions=len(self.regions)):
             return self._jitted(self.arrays, x)
 
-    # ---- traced (diagnostic) execution -----------------------------------
-    def _node_fn(self, nid: int):
-        """Per-node jit'd callables for :meth:`traced_call`, cached so
-        repeated traced calls never re-trace.  Kept apart from the fused
-        closure: building these does not touch ``trace_count``."""
-        fn = self._node_jits.get(nid)
-        if fn is None:
-            node = self.graph.nodes[nid]
-            if nid in self._region_head:
-                from repro.runtime import regions as _regions
+    # ---- device-op attribution -----------------------------------------
+    def op_scopes(self, x) -> dict[str, dict[str, str]]:
+        """``{module: {instruction: scope}}`` of the executable that serves
+        inputs shaped like ``x`` (:mod:`repro.obs.scopes`): which graph
+        node (``n<id>.<op>``) or region (``region.<ids>``) each device op
+        of the compiled forward belongs to.  Built once per input shape
+        from the executable already compiled for it (no retrace, no
+        second compile: ``trace_count`` stays flat)."""
+        key = (tuple(x.shape), str(x.dtype))
+        if key not in self._op_scopes:
+            text = self._jitted.lower(self.arrays, x).compile().as_text()
+            self._op_scopes[key] = _scopes.op_scopes(text,
+                                                     self._arg_scopes())
+        return self._op_scopes[key]
 
-                chain = self._region_head[nid]
-                fn = jax.jit(lambda arrays, x:
-                             _regions.eval_chain(chain, arrays, x))
-            else:
-                op, attrs = node.op, dict(node.attrs)
-                backend = self.backends.get(nid, "xla")
-                tile = self.tile_configs.get(nid)
-                fn = jax.jit(lambda params, *ins: eval_node(
-                    op, attrs, params, list(ins), backend=backend,
-                    tile=tile))
-            self._node_jits[nid] = fn
-        return fn
+    def _scope(self, nid: int) -> str:
+        """The named scope node ``nid`` is traced under in ``_run``."""
+        for chain in self.regions:
+            if nid in chain.node_ids:
+                return "region." + "+".join(map(str, chain.node_ids))
+        return f"n{nid}.{self.graph.nodes[nid].op}"
 
-    def traced_call(self, x: jnp.ndarray) -> jnp.ndarray:
-        """Per-node execution with one span per node / chain region.
-
-        The diagnostic answer to "where did this forward's time go":
-        walks the schedule host-side, blocking after every node so each
-        span's duration is real wall time (the fused ``__call__`` cannot
-        attribute time below the whole closure).  Bit-exact with
-        ``__call__`` — same backends, same tiles, same region evaluation
-        — and runs through its own per-node jit cache, so the fused
-        closure is never retraced (``trace_count`` unchanged).  Blocking
-        per node forfeits inter-node overlap: this is a profiling tool,
-        not a serving path.
-        """
+    def _arg_scopes(self) -> dict[str, str]:
+        """Argument paths (``_run``'s ``arrays`` and ``x``) to the scope of
+        the node that reads them: XLA's copies of an argument carry the
+        argument's path, not a scope."""
         g = self.graph
-        env: dict[int, Any] = {}
-        with _trace.span("executor.traced_call", "runtime",
-                         nodes=len(self._schedule)):
-            for nid in self._schedule:
-                node = g.nodes[nid]
-                if node.op == "input":
-                    env[nid] = x
-                    continue
-                if nid in self._region_members:
-                    chain = self._region_head.get(nid)
-                    if chain is None:
-                        continue
-                    label = "+".join(map(str, chain.node_ids))
-                    with _trace.span(f"region.{label}", "executor",
-                                     op="chain", stages=len(chain.stages)):
-                        out = self._node_fn(nid)(self.arrays,
-                                                 env[node.inputs[0]])
-                        jax.block_until_ready(out)
-                    env[chain.tail] = out
-                    continue
-                with _trace.span(f"node.{node.op}", "executor", node=nid,
-                                 backend=self.backends.get(nid)) as sp:
-                    out = self._node_fn(nid)(
-                        self.arrays.get(str(nid), {}),
-                        *[env[i] for i in node.inputs])
-                    jax.block_until_ready(out)
-                    sp.set(shape=list(getattr(out, "shape", ())))
-                env[nid] = out
-        return env[g.output_id]
+        out = {f"arrays['{k}']": self._scope(int(k)) for k in self.arrays}
+        readers = [nid for nid in self._schedule
+                   if any(g.nodes[i].op == "input"
+                          for i in g.nodes[nid].inputs)]
+        if readers:
+            out["x"] = self._scope(readers[0])
+        return out
 
     # ---- variants --------------------------------------------------------
     def with_backends(self, backends: str | Mapping[int, str],

@@ -43,7 +43,6 @@ sampling, single-host loop.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Any, Callable
 
@@ -71,7 +70,7 @@ class LMServer:
     n_slots: int
     max_seq: int
     eos_id: int | None = None
-    clock: Callable[[], float] = time.monotonic
+    clock: Callable[[], float] = _trace.clock
     retry: RetryPolicy | None = dataclasses.field(
         default_factory=RetryPolicy)
     max_queue: int | None = None

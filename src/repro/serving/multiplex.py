@@ -40,6 +40,7 @@ from __future__ import annotations
 import time
 from typing import Any, Callable
 
+from repro.obs import trace as _trace
 from repro.serving.scheduler import Request
 from repro.serving.server import InferenceServer
 
@@ -71,7 +72,7 @@ class MultiTenantServer:
     lanes restored from AOT artifacts).
     """
 
-    def __init__(self, *, clock: Callable[[], float] = time.monotonic,
+    def __init__(self, *, clock: Callable[[], float] = _trace.clock,
                  sleep: Callable[[float], None] | None = None,
                  **default_server_kw):
         self.clock = clock

@@ -23,11 +23,12 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import time
 from collections import deque
 from typing import Any, Callable
 
 import numpy as np
+
+from repro.obs.trace import clock as _clock
 
 
 #: The terminal request outcomes (DESIGN.md §11): every submitted
@@ -38,7 +39,7 @@ OUTCOMES = ("served", "shed", "error", "rejected")
 @dataclasses.dataclass
 class Request:
     payload: Any
-    arrival_s: float = dataclasses.field(default_factory=time.monotonic)
+    arrival_s: float = dataclasses.field(default_factory=_clock)
     deadline_s: float | None = None   # max queue residency; None = patient
     id: int = dataclasses.field(
         default_factory=itertools.count().__next__)
@@ -131,7 +132,7 @@ class BatchScheduler:
         """Pop every expired request (done, result=None); count them."""
         if not self._queue:
             return []
-        now = time.monotonic() if now is None else now
+        now = _clock() if now is None else now
         self._queue, shed = shed_expired_requests(self._queue, now)
         self.dropped += len(shed)
         return shed
@@ -142,7 +143,7 @@ class BatchScheduler:
             return False
         if len(self._queue) >= self.max_batch:
             return True
-        now = time.monotonic() if now is None else now
+        now = _clock() if now is None else now
         return (now - self._queue[0].arrival_s) >= self.max_wait_s
 
     def next_batch(self, now: float | None = None,
@@ -152,7 +153,7 @@ class BatchScheduler:
         policy — final flush).  Requests in retry backoff
         (``not_before`` in the future) keep their queue position but are
         passed over until their delay elapses."""
-        now = time.monotonic() if now is None else now
+        now = _clock() if now is None else now
         self.shed_expired(now)
         if not (self._queue if force else self.ready(now)):
             return None

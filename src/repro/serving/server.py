@@ -85,20 +85,26 @@ class _InFlight:
     ``row_idx`` maps each request to its row of the device output (rows
     of requests whose preprocessing failed are zero-filled and skipped);
     ``mode`` is the backend the executable ran under (degradation);
-    ``t_dispatch``/``stage_s`` feed the flight recorder at scatter."""
+    ``t_assembled``/``t_dispatch``/``stage_s`` and the per-request
+    ``preprocess_s`` (None without a hook) feed the flight recorder at
+    scatter."""
 
-    __slots__ = ("batch", "row_idx", "out", "bucket", "t_dispatch",
-                 "stage_s", "mode", "probing")
+    __slots__ = ("batch", "row_idx", "out", "bucket", "t_assembled",
+                 "t_dispatch", "stage_s", "preprocess_s", "mode",
+                 "probing")
 
     def __init__(self, batch: list[Request], row_idx: list[int], out,
-                 bucket: int, t_dispatch: float, stage_s: float,
+                 bucket: int, t_assembled: float, t_dispatch: float,
+                 stage_s: float, preprocess_s: list[float] | None,
                  mode: str | None, probing: bool = False):
         self.batch = batch
         self.row_idx = row_idx
         self.out = out
         self.bucket = bucket
+        self.t_assembled = t_assembled
         self.t_dispatch = t_dispatch
         self.stage_s = stage_s
+        self.preprocess_s = preprocess_s
         self.mode = mode
         self.probing = probing
 
@@ -134,7 +140,8 @@ class InferenceServer:
                      over its devices.
     flight_capacity: size of the flight-recorder ring (recent request
                      records for postmortems; ``server.flight.dump()``).
-    clock:           injectable monotonic clock (tests use a fake).
+    clock:           injectable monotonic clock (tests use a fake);
+                     defaults to the program clock ``obs.trace.clock``.
 
     Resilience (DESIGN.md §11)
     --------------------------
@@ -169,10 +176,16 @@ class InferenceServer:
 
     Observability (DESIGN.md §10): when a tracer is installed
     (``repro.obs.trace.install()``) each serving stage emits a span —
-    ``serve.submit`` (instant), ``serve.assemble``, ``serve.stage``,
+    ``serve.submit`` (instant), ``serve.assemble``, ``serve.stage``
+    (with one ``serve.preprocess`` per row under a hook),
     ``serve.dispatch``, ``serve.device``, ``serve.scatter`` — all
     host-side, so tracing never retraces the compiled executables.
-    Disabled (the default), every site is one global read.
+    Disabled (the default), every site is one global read.  Always on,
+    whatever the tracer: each served request's flight record carries
+    its stage stamps on the server clock — ``arrival_s``,
+    ``assembled_s`` (its batch left the scheduler), ``dispatched_s``,
+    ``ready_s`` (the readback returned), ``done_s`` — with ``stage_s``
+    per batch and, under a hook, the row's own ``preprocess_s``.
     """
 
     def __init__(self, engine, *, max_batch: int = 8,
@@ -185,7 +198,7 @@ class InferenceServer:
                  mesh=None, data_axis: str = "data",
                  placement=None,
                  flight_capacity: int = 256,
-                 clock: Callable[[], float] = time.monotonic,
+                 clock: Callable[[], float] = _trace.clock,
                  retry: RetryPolicy | None = RetryPolicy(),
                  max_queue: int | None = None,
                  validate: bool = True,
@@ -301,6 +314,15 @@ class InferenceServer:
                 jax.block_until_ready(exe(x))
                 timings[b] = time.perf_counter() - t0
         return timings
+
+    def op_scopes(self, bucket: int) -> dict[str, dict[str, str]]:
+        """Which graph node, chain region or head each device op of this
+        bucket's executable belongs to, per compiled module
+        (``{module: {HLO instruction: scope}}``, :mod:`repro.obs.scopes`)
+        — the map that charges a profiler trace's ops to nodes.  Built
+        once per bucket executable, from the one already compiled."""
+        x = self._place(np.zeros(self.engine._plan_shape(bucket), np.uint8))
+        return self._executable(bucket).op_scopes(x)
 
     # ---- placement --------------------------------------------------------
     def _place(self, x_np: np.ndarray):
@@ -441,15 +463,19 @@ class InferenceServer:
     # ---- dispatch / scatter ----------------------------------------------
     def _stage_rows(self, batch: list[Request], payloads: list[Any]
                     ) -> tuple[list[np.ndarray], list[Request],
-                               list[int], list[tuple[Request, Exception]]]:
+                               list[int], list[float] | None,
+                               list[tuple[Request, Exception]]]:
         """Host staging with per-row fault isolation: a payload whose
         conversion/preprocess raises is zero-filled (zeros are inert —
         the same trick bucket padding uses) so the rest of the batch
-        still dispatches; its request is returned as a failure."""
+        still dispatches; its request is returned as a failure.  Under a
+        hook, each kept request's hook seconds come back alongside."""
         zero_row: np.ndarray | None = None
         rows: list[np.ndarray | None] = []
         kept: list[Request] = []
         row_idx: list[int] = []
+        pre_s: list[float] | None = [] if self.preprocess is not None \
+            else None
         failures: list[tuple[Request, Exception]] = []
         for i, p in enumerate(payloads):
             r = batch[i] if i < len(batch) else None
@@ -458,11 +484,17 @@ class InferenceServer:
                 if r is not None and _faults._PLAN is not None:
                     _faults.maybe_fault("server.preprocess", req=r.id)
                 if self.preprocess is not None:
-                    row = self.preprocess(row)
+                    with _trace.span("serve.preprocess", "serve",
+                                     req=None if r is None else r.id):
+                        t0 = self.clock()
+                        row = self.preprocess(row)
+                        dt = self.clock() - t0
                 rows.append(row)
                 if r is not None:
                     kept.append(r)
                     row_idx.append(i)
+                    if pre_s is not None:
+                        pre_s.append(dt)
             except Exception as e:      # noqa: BLE001 — isolate the row
                 rows.append(None)
                 if r is not None:
@@ -470,17 +502,17 @@ class InferenceServer:
         if zero_row is None:
             zero_row = np.zeros(self.engine._plan_shape(1)[1:], np.uint8)
         return ([row if row is not None else zero_row for row in rows],
-                kept, row_idx, failures)
+                kept, row_idx, pre_s, failures)
 
     def _dispatch(self, batch: list[Request], payloads: list[Any],
-                  mode: str | None = None
+                  t_assembled: float, mode: str | None = None
                   ) -> tuple[_InFlight | None,
                              list[tuple[Request, Exception]]]:
         t0 = self.clock()
         with _trace.span("serve.stage", "serve", bucket=len(payloads),
                          n_real=len(batch)):
-            rows, kept, row_idx, failures = self._stage_rows(batch,
-                                                             payloads)
+            rows, kept, row_idx, pre_s, failures = self._stage_rows(
+                batch, payloads)
         if not kept:
             return None, failures
         if _faults._PLAN is not None:
@@ -494,11 +526,11 @@ class InferenceServer:
         t1 = self.clock()
         self.dispatched_rows += len(rows)
         self._metrics.mark_dispatch(bucket=len(rows))
-        return (_InFlight(kept, row_idx, out, len(rows), t1, t1 - t0,
-                          mode), failures)
+        return (_InFlight(kept, row_idx, out, len(rows), t_assembled, t1,
+                          t1 - t0, pre_s, mode), failures)
 
     def _try_dispatch(self, batch: list[Request], payloads: list[Any],
-                      now: float) -> _InFlight | None:
+                      now: float, t_assembled: float) -> _InFlight | None:
         """Dispatch with the full failure protocol: per-bucket mode
         selection (this bucket's degradation ladder + quarantine
         re-probe), batch-level retry on failure, per-row failure
@@ -514,7 +546,8 @@ class InferenceServer:
             mode, probing = ((probe, True) if probe is not None
                              else (self.health.mode_for(bucket), False))
         try:
-            flight, failures = self._dispatch(batch, payloads, mode=mode)
+            flight, failures = self._dispatch(batch, payloads, t_assembled,
+                                              mode=mode)
         except Exception as e:          # noqa: BLE001 — never kill the loop
             self._on_batch_failure(batch, e, now, mode, probing, bucket)
             return None
@@ -565,6 +598,7 @@ class InferenceServer:
     def _scatter(self, flight: _InFlight) -> list[Request]:
         with _trace.span("serve.device", "serve", bucket=flight.bucket):
             host = self._readback(flight)   # the only blocking point
+            ready = self.clock()
         now = self.clock()
         with _trace.span("serve.scatter", "serve",
                          n_real=len(flight.batch)):
@@ -572,14 +606,18 @@ class InferenceServer:
                 r.resolve("served", host[i])
                 self._journal_resolve(r)
         self._metrics.record([now - r.arrival_s for r in flight.batch])
-        for r in flight.batch:
-            self.flight.record(
+        pre_s = flight.preprocess_s
+        for k, r in enumerate(flight.batch):
+            rec = self.flight.record(
                 id=r.id, outcome="served", bucket=flight.bucket,
                 arrival_s=r.arrival_s, deadline_s=r.deadline_s,
-                dispatched_s=flight.t_dispatch, done_s=now,
+                assembled_s=flight.t_assembled,
+                dispatched_s=flight.t_dispatch, ready_s=ready, done_s=now,
                 queue_s=flight.t_dispatch - r.arrival_s,
                 stage_s=flight.stage_s, latency_s=now - r.arrival_s,
                 attempts=r.attempts, mode=flight.mode)
+            if pre_s is not None:
+                rec["preprocess_s"] = pre_s[k]
         return flight.batch
 
     def _try_scatter(self, flight: _InFlight,
@@ -645,7 +683,7 @@ class InferenceServer:
             with _trace.span("serve.assemble", "serve"):
                 got = self.scheduler.padded_batch(now, force=force)
             if got is not None:
-                flight = self._try_dispatch(*got, now)
+                flight = self._try_dispatch(*got, now, self.clock())
         done: list[Request] = []
         if not self.async_dispatch:
             if flight is not None:

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core.bnn_model import BConv, BDense, FloatConv, FloatDense, Pool
 from repro.models import paper_nets
+from repro.obs import scopes as _scopes
 from repro.serving import InferenceServer, PhoneBitEngine
 from repro.workloads import postprocess as post
 from repro.workloads import preprocess as pre
@@ -90,6 +91,26 @@ def checkpoint_params(spec, seed: int = 0) -> list[dict]:
     return params
 
 
+class _Served:
+    """One bucket's image->prediction executable: the engine's forward,
+    then the head, dispatched back to back."""
+
+    __slots__ = ("fwd", "head")
+
+    def __init__(self, fwd, head):
+        self.fwd, self.head = fwd, head
+
+    def __call__(self, x):
+        return self.head(self.fwd(x))
+
+    def op_scopes(self, x) -> dict[str, dict[str, str]]:
+        """The scope of every device op of both modules, forward and head
+        (``GraphExecutor.op_scopes``; the head's ops read ``head``)."""
+        y = jax.eval_shape(self.fwd, x)
+        text = self.head.lower(y).compile().as_text()
+        return {**self.fwd.op_scopes(x), **_scopes.op_scopes(text)}
+
+
 class WorkloadEngine:
     """A PhoneBitEngine with the workload's postprocess head fused onto
     its per-bucket executable surface.
@@ -111,7 +132,8 @@ class WorkloadEngine:
 
         def traced_head(y):
             self._head_trace_count += 1   # trace time only
-            return head(y)
+            with jax.named_scope("head"):  # its device ops' scope
+                return head(y)
 
         self._head_jit = jax.jit(traced_head)
         self._compiled: dict[tuple, Callable] = {}
@@ -129,8 +151,7 @@ class WorkloadEngine:
             fwd = self.engine.compile(batch_size, donate_input=donate_input,
                                       data_parallel=data_parallel, mode=mode,
                                       **kw)
-            self._compiled[key] = \
-                lambda x, fwd=fwd: self._head_jit(fwd(x))
+            self._compiled[key] = _Served(fwd, self._head_jit)
         return self._compiled[key]
 
     def _plan_shape(self, batch: int | None = None):

@@ -15,15 +15,17 @@ registry primitives, the flight recorder ring, trace export/validation,
 and benchmark provenance stamping.
 """
 
+import itertools
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bnn_model
 from repro.core.bnn_model import BConv, FloatDense, Pool
-from repro.obs import flight, metrics, provenance, trace
+from repro.obs import flight, metrics, provenance, scopes, trace
 from repro.serving import InferenceServer, PhoneBitEngine
 
 
@@ -334,41 +336,124 @@ def _timed_spans(n):
 
 
 # --------------------------------------------------------------------------
-# Per-node executor spans (traced_call)
+# Node scopes on the served executable
 # --------------------------------------------------------------------------
 
-class TestTracedCall:
-    def test_traced_call_bit_exact_no_retrace(self, tiny_engine, tracer):
+def _scope_set(op_maps):
+    return {sc for mp in op_maps.values() for sc in mp.values()}
+
+
+def _chain_engine(mode="vpu_chain"):
+    spec = [BConv(3, 16, kernel=3, stride=1, pad=1, first=True),
+            BConv(16, 16, kernel=3, stride=1, pad=1),
+            Pool(2, 2), FloatDense(8 * 8 * 16, 4)]
+    params = bnn_model.init_params(jax.random.key(1), spec)
+    return PhoneBitEngine.from_trained(params, spec, (16, 16),
+                                       matmul_mode=mode)
+
+
+class TestNodeScopes:
+    @pytest.mark.parametrize("op_name,scope", [
+        ("jit(_run)/n3.packed_conv_pool/jit(chain_conv)/direct_conv_pool/"
+         "pallas_call", "n3.packed_conv_pool"),
+        ("jit(_run)/region.3+5/jit(chain_conv)/jit(_pad)/pad", "region.3+5"),
+        ("jit(traced_head)/head/jit(nms)/while", "head"),
+        ("jit(_run)/shard_map/n12.unpack_pm1/shift_right_logical",
+         "n12.unpack_pm1"),
+        ("x", "none"),
+        ("", "none"),
+    ])
+    def test_scope_of(self, op_name, scope):
+        assert scopes.scope_of(op_name) == scope
+
+    def test_argument_copies_charged_to_their_reader(self):
+        """XLA's layout copies of an argument carry its path (quotes
+        escaped in the HLO text), not a scope: the map charges them to
+        the node that reads the argument."""
+        text = "\n".join([
+            "HloModule jit__run, entry_computation_layout={}",
+            "  %copy.6 = s32[3] copy(s32[3] %p), metadata={op_name="
+            "\"arrays[\\'3\\'][\\'w_packed\\']\"}",
+            "  %copy.7 = u8[3] copy(u8[3] %x.1), metadata={op_name=\"x\"}",
+            "  %copy-start = (s32[3]) copy-start(s32[3] %q)",
+            "  ROOT %chain_region.3 = s32[3] custom-call(s32[3] %copy.6), "
+            "metadata={op_name=\"jit(_run)/region.3+5/chain_region/"
+            "pallas_call\" stack_frame_id=2}"])
+        got = scopes.op_scopes(text, {"arrays['3']": "region.3+5",
+                                      "x": "n1.bitplane_expand"})
+        assert got == {"jit__run": {"copy.6": "region.3+5",
+                                    "copy.7": "n1.bitplane_expand",
+                                    "copy-start": "none",
+                                    "chain_region.3": "region.3+5"}}
+
+    def test_every_node_scoped_bit_exact_no_retrace(self, tiny_engine):
+        """The served executable's ops carry their node's scope; building
+        the map retraces and recompiles nothing and changes no bit."""
         exe = tiny_engine.compile(2)
-        x = np.stack(_images(2))
+        x = jnp.asarray(np.stack(_images(2)))
         ref = np.asarray(exe(x))
         before = tiny_engine.trace_count
-        got = exe.traced_call(x)
-        np.testing.assert_array_equal(np.asarray(got), ref)
-        assert tiny_engine.trace_count == before     # own jit cache
-        node_spans = tracer.spans("node.")
-        assert len(node_spans) >= 3                  # conv_pool/dense/...
-        assert all("dur" in e and e["dur"] >= 0 for e in node_spans)
-        (walk,) = tracer.spans("executor.traced_call")
-        assert walk["args"]["nodes"] >= len(node_spans)
+        maps = exe.op_scopes(x)
+        assert exe.op_scopes(x) is maps                  # built once
+        assert tiny_engine.trace_count == before
+        (module,) = maps
+        assert module.startswith("jit_")
+        want = {f"n{nid}.{n.op}" for nid, n in exe.graph.nodes.items()
+                if n.op != "input"}
+        assert want <= _scope_set(maps)
+        np.testing.assert_array_equal(np.asarray(exe(x)), ref)
+        assert tiny_engine.trace_count == before
 
-    def test_traced_call_region_spans(self, tracer):
-        """A vpu_chain executor reports fused regions as region.* spans
-        and still matches the fused __call__ bit for bit."""
-        spec = [BConv(3, 16, kernel=3, stride=1, pad=1, first=True),
-                BConv(16, 16, kernel=3, stride=1, pad=1),
-                Pool(2, 2), FloatDense(8 * 8 * 16, 4)]
-        params = bnn_model.init_params(jax.random.key(1), spec)
-        eng = PhoneBitEngine.from_trained(params, spec, (16, 16),
-                                          matmul_mode="vpu_chain")
+    def test_chain_regions_scoped(self):
+        """A vpu_chain executor's fused regions are scopes of their own
+        (member nodes never appear alone), bit-exact with per-node
+        serving."""
+        eng = _chain_engine()
         exe = eng.compile(1)
-        x = np.stack(_images(1))
-        got = exe.traced_call(x)
-        np.testing.assert_array_equal(np.asarray(got),
-                                      np.asarray(exe(x)))
-        regions = tracer.spans("region.")
-        assert len(regions) == len(exe.regions) >= 1
-        assert all(e["args"]["op"] == "chain" for e in regions)
+        x = jnp.asarray(np.stack(_images(1)))
+        got = np.asarray(exe(x))
+        before = eng.trace_count
+        found = _scope_set(exe.op_scopes(x))
+        assert eng.trace_count == before
+        assert exe.regions
+        for chain in exe.regions:
+            assert "region." + "+".join(map(str, chain.node_ids)) in found
+            assert not any(sc.startswith(f"n{nid}.") for sc in found
+                           for nid in chain.node_ids)
+        ref = _chain_engine("xla").compile(1)(x)
+        np.testing.assert_array_equal(got, np.asarray(ref))
+
+    def test_tracing_on_changes_no_bit(self, tiny_engine):
+        exe = tiny_engine.compile(1)
+        x = jnp.asarray(np.stack(_images(1)))
+        off = np.asarray(exe(x))
+        before = tiny_engine.trace_count
+        trace.install(trace.Tracer(annotate_jax=True))
+        try:
+            on = np.asarray(exe(x))
+        finally:
+            trace.uninstall()
+        np.testing.assert_array_equal(on, off)
+        assert tiny_engine.trace_count == before
+
+    def test_server_maps_forward_and_head(self):
+        """Per bucket, the server maps both modules it dispatches: the
+        forward's nodes and the postprocess head, after compile_buckets
+        and with no retrace."""
+        from repro import workloads
+
+        wl = workloads.get("yolov2_tiny_voc", variant="tiny", seed=3)
+        server = wl.server(buckets=(1, 2), max_batch=2, preprocess=None)
+        server.compile_buckets()
+        before = wl.engine.trace_count
+        maps = server.op_scopes(2)
+        assert len(maps) == 2
+        fwd, head = (maps[m] for m in sorted(maps, key=lambda m: "head" in m))
+        assert "head" in set(head.values()) <= {"head", "none"}
+        assert "n1.bitplane_expand" in set(fwd.values())
+        server.submit(np.zeros((32, 32, 3), np.uint8))
+        server.drain()
+        assert wl.engine.trace_count == before
 
     def test_fused_call_whole_span_when_enabled(self, tiny_engine,
                                                 tracer):
@@ -376,6 +461,73 @@ class TestTracedCall:
         exe(np.stack(_images(1)))
         (ev,) = tracer.spans("executor.call")
         assert ev["args"]["nodes"] > 0
+
+
+# --------------------------------------------------------------------------
+# Request stage stamps in the flight recorder
+# --------------------------------------------------------------------------
+
+def _ticking():
+    """A fake clock that moves 1 ms on every read."""
+    ticks = itertools.count()
+    return lambda: next(ticks) * 1e-3
+
+
+class TestFlightStages:
+    @pytest.mark.parametrize("hook", [False, True])
+    def test_stamps_ordered(self, tiny_engine, hook):
+        server = InferenceServer(
+            tiny_engine, buckets=(1, 2), max_batch=2, clock=_ticking(),
+            preprocess=(lambda p: p) if hook else None)
+        for img in _images(3):
+            server.submit(img)
+        server.drain()
+        recs = [r for r in server.flight.dump() if r["outcome"] == "served"]
+        assert len(recs) == 3
+        for r in recs:
+            assert (r["arrival_s"] <= r["assembled_s"] < r["dispatched_s"]
+                    < r["ready_s"] < r["done_s"])
+            assert r["queue_s"] == r["dispatched_s"] - r["arrival_s"]
+            assert r["stage_s"] > 0
+            if hook:
+                assert 0 < r["preprocess_s"] <= r["stage_s"]
+            else:
+                assert "preprocess_s" not in r
+
+    def test_preprocess_spans_when_tracing(self, tiny_engine, tracer):
+        server = InferenceServer(tiny_engine, buckets=(2,), max_batch=2,
+                                 preprocess=lambda p: p)
+        reqs = [server.submit(img) for img in _images(2)]
+        server.drain()
+        pre = tracer.spans("serve.preprocess")
+        assert sorted(e["args"]["req"] for e in pre) == \
+            sorted(r.id for r in reqs)
+        (stage,) = tracer.spans("serve.stage")
+        assert all(stage["ts"] <= e["ts"] and e["ts"] + e["dur"]
+                   <= stage["ts"] + stage["dur"] for e in pre)
+
+    def test_one_program_clock(self, tiny_engine):
+        """Flight stamps, spans and the servers' default clocks read one
+        function: the benchmark's clock."""
+        import time
+
+        assert trace.clock is time.perf_counter
+        assert trace.Tracer().clock is trace.clock
+        assert InferenceServer(tiny_engine).clock is trace.clock
+        assert metrics.ServingMetrics()._clock is trace.clock
+
+    def test_clock_anchor_written_once(self):
+        """An annotating tracer writes one ``obs.clock`` annotation, at
+        its first span, and keeps the clock reading taken inside it."""
+        reads = itertools.count(5.0)
+        t = trace.Tracer(clock=lambda: next(reads), annotate_jax=True)
+        assert t.anchor_s is None                   # 5.0: the epoch
+        with t.span("serve.a"):                     # 6.0: the anchor
+            pass
+        with t.span("serve.b"):
+            pass
+        assert t.anchor_s == 6.0
+        assert [e["name"] for e in t.events] == ["serve.a", "serve.b"]
 
 
 # --------------------------------------------------------------------------

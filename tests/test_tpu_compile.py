@@ -8,6 +8,8 @@ The topology is described inside a fixture, only once a test of this file
 runs; the TPU compiler is loaded by whichever worker runs this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -53,10 +55,13 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, name):
+    """Compile for the described chip; the kernel must come out as a
+    custom call named ``name`` (its name on a device trace)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", text), name
 
 
 I32 = jnp.int32
@@ -65,13 +70,13 @@ I32 = jnp.int32
 def test_xnor_popcount_matmul_yolo_conv8(one_chip):
     m, n, w = YOLO_CONV8["m"], YOLO_CONV8["n"], YOLO_CONV8["w"]
     _compile(lambda a, b: xnor_popcount_matmul(a, b), one_chip,
-             ((m, w), I32), ((n, w), I32))
+             ((m, w), I32), ((n, w), I32), name="xnor_matmul")
 
 
 def test_mxu_pm1_matmul_yolo_conv8(one_chip):
     m, n, w = YOLO_CONV8["m"], YOLO_CONV8["n"], YOLO_CONV8["w"]
     _compile(lambda a, b: mxu_pm1_matmul(a, b, k_valid=32 * w), one_chip,
-             ((m, w), I32), ((n, w), I32))
+             ((m, w), I32), ((n, w), I32), name="pm1_matmul")
 
 
 def test_fused_conv_bn_binarize_alexnet_conv2(one_chip):
@@ -79,7 +84,7 @@ def test_fused_conv_bn_binarize_alexnet_conv2(one_chip):
     m, w = c["h"] * c["h"], c["k"] * c["k"] * c["cw"]
     _compile(lambda a, b, t, s: fused_matmul_bn_binarize(a, b, t, s),
              one_chip, ((m, w), I32), ((c["o"], w), I32),
-             ((c["o"],), I32), ((c["o"],), I32))
+             ((c["o"],), I32), ((c["o"],), I32), name="matmul_bn_binarize")
 
 
 @pytest.mark.parametrize("pool", [None, (3, 2)])
@@ -91,7 +96,8 @@ def test_direct_conv_bn_binarize_alexnet_conv2(one_chip, pool):
                  x, w, t, s, kh=c["k"], kw=c["k"], pad=c["pad"], **pool_kw),
              one_chip, ((1, c["h"], c["h"], c["cw"]), I32),
              ((c["o"], c["k"] * c["k"] * c["cw"]), I32),
-             ((c["o"],), I32), ((c["o"],), I32))
+             ((c["o"],), I32), ((c["o"],), I32),
+             name="direct_conv" if pool is None else "direct_conv_pool")
 
 
 def test_chain_conv_yolo_first_region_416(one_chip):
@@ -112,9 +118,9 @@ def test_chain_conv_yolo_first_region_416(one_chip):
 
     _compile(fn, one_chip, (in_shape, I32), ((16, 72), I32), ((72,), I32),
              ((16,), I32), ((16,), I32), ((32, 9), I32), ((32,), I32),
-             ((32,), I32))
+             ((32,), I32), name="chain_region")
 
 
 def test_bitplane_pack_416(one_chip):
     _compile(lambda x: bitplane_pack(x), one_chip,
-             ((1, 416, 416, 3), jnp.uint8))
+             ((1, 416, 416, 3), jnp.uint8), name="bitplane_pack")
