@@ -9,6 +9,9 @@ direct_conv_bn_binarize  direct (im2col-free) fused conv: VMEM-resident
                          threshold + bit-pack + OR-pool epilogue
                          (DESIGN.md §5)
 bitplane_pack            first-layer bit-plane split+pack (C8, Eqn 2)
+image_conv               the first binary conv as one exact integer conv on
+                         the MXU, straight from the uint8 image (XLA, not
+                         Pallas; DESIGN.md §3.6)
 mxu_pm1_matmul           beyond-paper MXU path (unpack-to-bf16 in VMEM)
 flash_attention          fused attention (score chain never leaves VMEM —
                          the LM/DiT/ViT hot-spot; custom_vjp bwd)

@@ -25,7 +25,8 @@ fast engine.
 from repro.runtime.autotune import (Autotuner, cache_path,
                                     default_candidates)
 from repro.runtime.executor import (ALL_MODES, BACKENDS, CHAIN_BACKEND,
-                                    GraphExecutor, valid_backends)
+                                    IMAGE_BACKEND, GraphExecutor,
+                                    valid_backends)
 from repro.runtime.graph import (DISPATCHABLE_OPS, Graph, Node, TensorType,
                                  infer_types, lower_packed, lower_trained)
 from repro.runtime.memory import MemoryPlan, VmemPlan, plan_memory, vmem_plan
@@ -40,8 +41,9 @@ from repro.runtime.regions import (Chain, build_chain, chain_executor,
 
 __all__ = [
     "ALL_MODES", "Autotuner", "BACKENDS", "CHAIN_BACKEND", "Chain",
-    "DISPATCHABLE_OPS", "Graph", "GraphExecutor", "MemoryPlan", "Node",
-    "StagePlan", "StagedExecutor", "TensorType", "VmemPlan",
+    "DISPATCHABLE_OPS", "Graph", "GraphExecutor", "IMAGE_BACKEND",
+    "MemoryPlan", "Node", "StagePlan", "StagedExecutor", "TensorType",
+    "VmemPlan",
     "absorb_pools", "assign_layouts", "build_chain", "cache_path",
     "chain_executor", "chain_report", "cut_candidates",
     "default_candidates", "default_pipeline", "fuse_epilogues",

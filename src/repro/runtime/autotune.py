@@ -33,6 +33,9 @@ marked ``reused_across_batch`` so reports can tell a measured winner from
 an inherited one).  Batch-agnostic records persist to disk alongside the
 exact ones under a ``batchless::`` key prefix.
 
+A first binary conv over uint8 pixels is not timed: it takes the
+executor's ``mxu_image`` lowering by its layer type (DESIGN.md §3.6).
+
 Fused regions (DESIGN.md §9) get their own sweep: :meth:`tune_chains`
 times the chain megakernel over per-chain tile shapes — a new search
 space, since a chain tile couples every stage through halo growth — and
@@ -61,9 +64,10 @@ import numpy as np
 from repro.cache import AUTOTUNE_FILE
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _trace
-from repro.runtime.executor import (BACKENDS, GraphExecutor, eval_node,
-                                    valid_backends)
-from repro.runtime.graph import DISPATCHABLE_OPS, Graph, infer_types
+from repro.runtime.executor import (BACKENDS, IMAGE_BACKEND, GraphExecutor,
+                                    eval_node, valid_backends)
+from repro.runtime.graph import (DISPATCHABLE_OPS, Graph, image_conv_expand,
+                                 infer_types)
 
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 
@@ -364,6 +368,11 @@ class Autotuner:
         for nid in graph.topo_order():
             node = graph.nodes[nid]
             if node.op not in DISPATCHABLE_OPS:
+                continue
+            if image_conv_expand(graph, nid) is not None:
+                # The first conv's MXU lowering goes by layer type, untimed
+                # (executor module docstring).
+                choices[nid] = IMAGE_BACKEND
                 continue
             in_t = types[node.inputs[0]]
             key = _node_signature(node, in_t.shape, self.candidates)

@@ -18,6 +18,15 @@ Per-node backends:
 * ``"vpu_direct_pool"`` the direct kernel with the OR-pool fused into its
                         epilogue — ``packed_conv_pool`` nodes only.
 
+A first binary conv over uint8 pixels has one more lowering, ``"mxu_image"``
+(:mod:`repro.kernels.image_conv`): one exact integer conv on the MXU that
+reads the image itself, so the bit-plane expansion feeding the node does
+not run.  It is chosen by the node's layer type, not timed: every such node
+takes it under the TPU modes in ``_IMAGE_MODES`` and under the engine's
+``auto``, while ``xla``/``xla_pm1`` (the bit-exact oracles) and the
+paper-faithful ``vpu_popcount`` keep the bit-plane path.  A first layer
+whose sum f32 cannot hold exactly keeps it too.
+
 Above the per-node backends sits the region-level ``"vpu_chain"`` mode
 (DESIGN.md §9): the executor accepts ``regions=`` — chains formed by
 :mod:`repro.runtime.regions` — and evaluates each whole region in one
@@ -52,7 +61,8 @@ from repro.core.bnn_model import _BN_EPS
 from repro.obs import metrics as _obs_metrics
 from repro.obs import scopes as _scopes
 from repro.obs import trace as _trace
-from repro.runtime.graph import DISPATCHABLE_OPS, Graph
+from repro.kernels import image_conv as _image_conv
+from repro.runtime.graph import DISPATCHABLE_OPS, Graph, image_conv_expand
 from repro.serving import faults as _faults
 
 BACKENDS = ("xla", "xla_pm1", "mxu_pm1", "vpu_popcount", "vpu_direct",
@@ -62,6 +72,11 @@ BACKENDS = ("xla", "xla_pm1", "mxu_pm1", "vpu_popcount", "vpu_direct",
 # ``matmul_mode``; per-node leftovers degrade along _FALLBACK.
 CHAIN_BACKEND = "vpu_chain"
 ALL_MODES = BACKENDS + (CHAIN_BACKEND,)
+# The first binary conv's MXU lowering (module docstring): a per-node
+# backend that only ``image_conv_expand`` nodes take, by rule.
+IMAGE_BACKEND = "mxu_image"
+_IMAGE_MODES = frozenset({CHAIN_BACKEND, "vpu_direct_pool", "vpu_direct",
+                          "mxu_pm1"})
 
 _IMPL = {"xla": "xor", "xla_pm1": "pm1", "mxu_pm1": "pm1"}
 # Graceful degradation when a single mode string hits an op it cannot run.
@@ -124,6 +139,11 @@ def _eval_packed_conv(a: dict, p: dict, x, backend: str, tile: dict):
     k, s, pad = a["kernel"], a["stride"], a["pad"]
     ww = p.get("word_weights")
     pool = _pool_attrs(a)
+    if backend == IMAGE_BACKEND:
+        # x is the uint8 image; the filters were prepared at build time.
+        return _image_conv.image_conv_bn_binarize(
+            x, p["w_image"], p["c1"], p["thresh"], kernel=k, stride=s,
+            pad=pad, pool=pool)
     block_kw = dict(tile) if backend.startswith("vpu") else {}
     if backend == "vpu_direct_pool":
         # Pool rides the direct kernel's epilogue: the pre-pool conv
@@ -220,6 +240,38 @@ def eval_node(node_op: str, attrs: dict, params: dict, inputs: list,
     raise ValueError(f"cannot evaluate op {node_op!r}")
 
 
+def graph_operands(graph: Graph) -> dict:
+    """The traced operands of a graph's executables, keyed by node id:
+    every node's params (IntegratedParams is a NamedTuple and flattens
+    naturally), plus, for each first conv that can run on the MXU, its
+    column-grouped ±1 filters and C1 (``image_conv.pm1_weights``),
+    prepared here, once.  Backend-independent, so an executable restored
+    from an artifact takes the same pytree; jit drops the operands a
+    backend does not read."""
+    arrays = {str(nid): dict(n.params)
+              for nid, n in graph.nodes.items() if n.params}
+    for nid, node in graph.nodes.items():
+        expand = image_conv_expand(graph, nid)
+        if expand is None:
+            continue
+        a = node.attrs
+        # The graph's input size, where known, picks a column group that
+        # divides the output width; any width runs either way.
+        out_width = (binary_conv.conv_out_size(
+            graph.input_hw[1], a["kernel"], a["stride"], a["pad"])
+            if graph.input_hw else 0)
+        w_packed = node.params["w_packed"]
+        prepared = _image_conv.pm1_weights(
+            w_packed, a["kernel"], a["stride"],
+            graph.nodes[expand].attrs["c_in"],
+            _image_conv.column_group(a["channels"], out_width))
+        if getattr(w_packed, "committed", False):
+            # beside the node's params, on the device they are pinned to
+            prepared = jax.device_put(prepared, w_packed.sharding)
+        arrays[str(nid)].update(zip(("w_image", "c1"), prepared))
+    return arrays
+
+
 class GraphExecutor:
     """Jit-compiled topological evaluator with frozen per-node backends.
 
@@ -254,14 +306,25 @@ class GraphExecutor:
                                             for c in self.regions):
             raise ValueError("regions overlap")
         if isinstance(backends, str):
-            backends = {nid: resolve_backend(n.op, backends)
+            mode = backends
+            backends = {nid: resolve_backend(n.op, mode)
                         for nid, n in graph.nodes.items()
                         if n.op in DISPATCHABLE_OPS}
+            if mode in _IMAGE_MODES:
+                backends.update({nid: IMAGE_BACKEND for nid in backends
+                                 if image_conv_expand(graph, nid) is not None})
         self.backends: dict[int, str] = {
             nid: b for nid, b in backends.items()
             if graph.nodes[nid].op in DISPATCHABLE_OPS}
         for nid, b in self.backends.items():
             op = graph.nodes[nid].op
+            if b == IMAGE_BACKEND:
+                if image_conv_expand(graph, nid) is None:
+                    raise ValueError(
+                        f"backend {b!r} needs a first binary conv over "
+                        f"uint8 pixels whose sum f32 holds exactly; node "
+                        f"{nid} ({op}) is not one")
+                continue
             if b not in BACKENDS:
                 raise ValueError(f"unknown backend {b!r} for node {nid}; "
                                  f"want one of {BACKENDS}")
@@ -271,10 +334,17 @@ class GraphExecutor:
         self.tile_configs: dict[int, dict] = {
             nid: dict(cfg) for nid, cfg in (tile_configs or {}).items()
             if nid in self.backends and cfg}
-        # Params are traced operands (a pytree keyed by node id);
-        # IntegratedParams is a NamedTuple and flattens naturally.
-        self.arrays = {str(nid): dict(n.params)
-                       for nid, n in graph.nodes.items() if n.params}
+        self.arrays = graph_operands(graph)
+        # MXU-lowered first convs read the image behind their bit-plane
+        # expansion; an expansion no other node reads is skipped.
+        expands = {nid: graph.nodes[nid].inputs[0]
+                   for nid, b in self.backends.items()
+                   if b == IMAGE_BACKEND and nid not in self._region_members}
+        self._feeds = {nid: graph.nodes[e].inputs[0]
+                       for nid, e in expands.items()}
+        cons = graph.consumers()
+        self._skipped = {e for e in expands.values()
+                         if all(c in expands for c in cons[e])}
         self._schedule = graph.topo_order()
         self.trace_count = 0
         self._op_scopes: dict[tuple, dict] = {}
@@ -313,6 +383,8 @@ class GraphExecutor:
             if node.op == "input":
                 env[nid] = x
                 continue
+            if nid in self._skipped:
+                continue
             if nid in self._region_members:
                 if nid in self._region_head:
                     from repro.runtime import regions as _regions
@@ -326,10 +398,17 @@ class GraphExecutor:
             with jax.named_scope(f"n{nid}.{node.op}"):
                 env[nid] = eval_node(
                     node.op, node.attrs, arrays.get(str(nid), {}),
-                    [env[i] for i in node.inputs],
+                    [env[i] for i in self._inputs(nid)],
                     backend=self.backends.get(nid, "xla"),
                     tile=self.tile_configs.get(nid))
         return env[g.output_id]
+
+    def _inputs(self, nid: int) -> tuple[int, ...]:
+        """The values node ``nid`` reads: its graph inputs, or the image
+        behind its bit-plane expansion when it runs as ``mxu_image``."""
+        if nid in self._feeds:
+            return (self._feeds[nid],)
+        return self.graph.nodes[nid].inputs
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         # Fault-injection site (DESIGN.md §11.1): host-side, before the
@@ -375,8 +454,9 @@ class GraphExecutor:
         g = self.graph
         out = {f"arrays['{k}']": self._scope(int(k)) for k in self.arrays}
         readers = [nid for nid in self._schedule
-                   if any(g.nodes[i].op == "input"
-                          for i in g.nodes[nid].inputs)]
+                   if nid not in self._skipped
+                   and any(g.nodes[i].op == "input"
+                           for i in self._inputs(nid))]
         if readers:
             out["x"] = self._scope(readers[0])
         return out

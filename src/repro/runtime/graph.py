@@ -59,6 +59,7 @@ from repro.core import bitplanes, packing
 from repro.core.binary_conv import conv_out_size, pack_conv_weights
 from repro.core.bnn_model import (BConv, BDense, FloatConv, FloatDense,
                                   LayerSpec, Pool)
+from repro.kernels.image_conv import exact
 
 # Ops whose output stays in the packed-word domain.
 PACKED_OPS = frozenset({
@@ -149,6 +150,24 @@ class Graph:
         if self.output_id not in self.nodes:
             raise ValueError("missing output node")
         self.topo_order()  # raises on cycles
+
+
+def image_conv_expand(graph: Graph, nid: int) -> int | None:
+    """The bit-plane expansion feeding node ``nid`` when ``nid`` is a first
+    binary conv that can read the uint8 image behind it directly, as one
+    exact integer conv on the MXU (:mod:`repro.kernels.image_conv`): the
+    expansion's input is the image, and the sum's range, 255·K, stays
+    exact under f32 accumulation.  None for every other node."""
+    node = graph.nodes[nid]
+    if (node.op not in ("packed_conv", "packed_conv_pool")
+            or not node.attrs.get("first")):
+        return None
+    expand = graph.nodes[node.inputs[0]]
+    if expand.op != "bitplane_expand":
+        return None
+    if not exact(node.attrs["kernel"] ** 2 * expand.attrs["c_in"]):
+        return None
+    return expand.id
 
 
 # --------------------------------------------------------------------------
@@ -256,6 +275,8 @@ def lower_packed(spec: Sequence[LayerSpec], packed: Sequence[dict],
     for layer, p in zip(spec, packed):
         if isinstance(layer, BConv):
             if layer.first:
+                # Executors that run this conv on the MXU read the image
+                # directly and skip the expansion (image_conv_expand).
                 cur = g.add("bitplane_expand", [cur],
                             attrs=dict(c_in=layer.c_in, channels=layer.c_in))
             cur = g.add(

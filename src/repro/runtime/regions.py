@@ -27,7 +27,11 @@ Region-formation rules (§9.1):
   (:func:`~repro.kernels.chain_conv.vmem_footprint`).  A run that exceeds
   the budget is split greedily — the longest fitting prefix becomes a
   region and the cut boundary spills to HBM;
-* runs shorter than ``min_nodes`` (default 2) stay on the per-node path.
+* runs shorter than ``min_nodes`` (default 2) stay on the per-node path;
+* a first binary conv that runs on the MXU straight from the uint8 image
+  (:func:`~repro.runtime.graph.image_conv_expand`, the executor's
+  ``mxu_image``) is no chain member: its region would start from the
+  bit-plane words that lowering never builds.
 
 Chains that fail any rule simply do not form; the executor evaluates
 those nodes per-node with its normal backend fallback — there is no
@@ -43,7 +47,8 @@ import jax.numpy as jnp
 
 from repro.kernels.chain_conv import (VMEM_BUDGET, StageSpec,
                                       chain_word_counts, plan_tile)
-from repro.runtime.graph import (PACKED_OPS, Graph, TensorType, infer_types)
+from repro.runtime.graph import (PACKED_OPS, Graph, TensorType,
+                                 image_conv_expand, infer_types)
 from repro.runtime.memory import VmemPlan, vmem_plan
 
 # A region must fit at a final tile of at least this many rows (or the
@@ -204,7 +209,7 @@ def _fits(chain: Chain) -> bool:
 
 def _chainable(graph: Graph, nid: int) -> bool:
     node = graph.nodes[nid]
-    if node.op not in CHAIN_OPS:
+    if node.op not in CHAIN_OPS or image_conv_expand(graph, nid) is not None:
         return False
     if node.op == "maxpool_pm1":
         # Only exactly an OR-pool when the input is already packed words.

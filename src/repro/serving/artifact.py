@@ -381,13 +381,13 @@ def load_artifact(engine, path, *, donate_input: bool = True,
                 head_fn = _deserialize_compiled(path / entry["head_file"],
                                                 entry["head_sha256"])
             if arrays is None:
+                from repro.runtime.executor import graph_operands
+
                 # Traced operands come from the *live* engine (weights
                 # are data, not part of the executable); building the
                 # operand pytree lowers the graph host-side — no jit,
                 # no traces.
-                arrays = {str(nid): dict(n.params)
-                          for nid, n in engine._graph.nodes.items()
-                          if n.params}
+                arrays = graph_operands(engine._graph)
             exe = AotExecutor(compiled, arrays, head_fn, bucket=bs,
                               donate_input=donate_input)
         engine._install_executable(bs, exe, donate_input=donate_input,

@@ -33,6 +33,11 @@ autotuner.  The original flat ``packed_forward`` walk is kept as the
                         persisted to disk (``REPRO_AUTOTUNE_CACHE=0``
                         opts out).
 
+Under every TPU mode and ``"auto"`` the first binary conv runs as one
+exact integer conv on the MXU straight from the uint8 image (executor
+backend ``mxu_image``, DESIGN.md §3.6); ``"xla"``, ``"xla_pm1"`` and
+``"vpu_popcount"`` keep the bit-plane first layer.
+
 The engine always lowers through :func:`runtime.fuse_pool_epilogue`, so
 conv+pool pairs serve as single ``packed_conv_pool`` nodes and the unpooled
 conv map drops out of the memory plan.

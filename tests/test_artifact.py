@@ -32,11 +32,16 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 
 SPEC = [BConv(3, 16, kernel=3, stride=1, pad=1, first=True),
         Pool(2, 2), FloatDense(8 * 8 * 16, 10)]
+# With a conv after the first, whose backend the autotuner times (the
+# first conv takes the MXU lowering untimed).
+TUNED_SPEC = [BConv(3, 16, kernel=3, stride=1, pad=1, first=True),
+              Pool(2, 2), BConv(16, 16, kernel=3, stride=1, pad=1),
+              FloatDense(8 * 8 * 16, 10)]
 
 
-def _engine(mode: str = "xla") -> PhoneBitEngine:
-    params = bnn_model.init_params(jax.random.key(0), SPEC)
-    return PhoneBitEngine.from_trained(params, SPEC, (16, 16),
+def _engine(mode: str = "xla", spec=SPEC) -> PhoneBitEngine:
+    params = bnn_model.init_params(jax.random.key(0), spec)
+    return PhoneBitEngine.from_trained(params, spec, (16, 16),
                                        matmul_mode=mode)
 
 
@@ -186,7 +191,7 @@ def test_autotune_table_rides_along(tmp_path, monkeypatch):
     from repro.serving.artifact import load_autotune_table
 
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "0")   # no disk warm start
-    src = _engine(mode="auto")
+    src = _engine(mode="auto", spec=TUNED_SPEC)
     export_artifact(src, tmp_path / "art", buckets=(1,))
     assert (tmp_path / "art" / "autotune.json").exists()
     # Adoption is checked against an ISOLATED tuner: the engine's own

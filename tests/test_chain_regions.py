@@ -178,7 +178,13 @@ class TestRegions:
         chain = chains[0]
         ops = [g.nodes[nid].op for nid in chain.node_ids]
         assert all(o in regions_mod.CHAIN_OPS for o in ops)
-        assert len(chain.stages) == 5          # 2 fused pools decompose
+        # The first conv runs on the MXU from the image, in no chain; the
+        # chain forms from conv2 on.
+        first = next(nid for nid in g.topo_order()
+                     if g.nodes[nid].attrs.get("first"))
+        assert first not in chain.node_ids
+        assert g.nodes[chain.head].inputs == (first,)
+        assert len(chain.stages) == 3          # the fused pool decomposes
         assert chain.plan.fits()
         assert chain.hbm_bytes_avoided() > 0
 
@@ -222,7 +228,7 @@ class TestRegions:
         may head a region but never sit inside one."""
         g, packed = _fused_graph(CHAIN_NET, (16, 16), seed=1)
         chains = partition_chains(g, (1, 16, 16, 3))
-        mid = chains[0].node_ids[1]
+        mid = chains[0].node_ids[0]
         # add a second consumer of `mid`
         g.add("or_pool", [mid], attrs=dict(window=2, stride=2,
                                            channels=g.nodes[mid]

@@ -299,7 +299,10 @@ class TestAutotuneTilesAndCache:
         choices, tiles = tuner.tune_with_tiles(gf, (1, 16, 16, 3))
         assert choices
         for nid, b in choices.items():
-            assert b in runtime.valid_backends(gf.nodes[nid].op)
+            if gf.nodes[nid].attrs.get("first"):
+                assert b == runtime.IMAGE_BACKEND      # by rule, untimed
+            else:
+                assert b in runtime.valid_backends(gf.nodes[nid].op)
         # direct candidates were swept with tile configs
         entry = next(iter(tuner.cache.values()))
         assert any("[" in lbl for lbl in entry["timings_ms"])

@@ -91,9 +91,12 @@ def engines():
     return {
         "float": build([BConv(3, 32, kernel=3, stride=1, pad=1, first=True),
                         Pool(2, 2), FloatDense(8 * 8 * 32, 10)]),
+        # conv2+pool2 and conv3 form a chain (the first conv runs on the
+        # MXU from the image, outside every chain).
         "packed": build([BConv(3, 32, kernel=3, stride=1, pad=1, first=True),
                          BConv(32, 32, kernel=3, stride=1, pad=1),
-                         Pool(2, 2), BDense(8 * 8 * 32, 64)]),
+                         Pool(2, 2), BConv(32, 32, kernel=3, stride=1, pad=1),
+                         BDense(8 * 8 * 32, 64)]),
     }
 
 

@@ -350,12 +350,17 @@ class TestAutotune:
         tuner = Autotuner(cache=cache, candidates=("xla", "xla_pm1"),
                           warmup=1, iters=1)
         choices = tuner.tune(g, x.shape)
-        assert choices and all(b in ("xla", "xla_pm1")
-                               for b in choices.values())
-        assert len(cache) == len(choices)
+        # The first conv takes the MXU lowering by rule, untimed; every
+        # other node is timed among the candidates and cached.
+        timed = {nid: b for nid, b in choices.items()
+                 if b != runtime.IMAGE_BACKEND}
+        assert choices[2] == runtime.IMAGE_BACKEND
+        assert timed and all(b in ("xla", "xla_pm1")
+                             for b in timed.values())
+        assert len(cache) == len(timed)
         # second tune hits the cache (no new entries, same winners)
         assert tuner.tune(g, x.shape) == choices
-        assert len(cache) == len(choices)
+        assert len(cache) == len(timed)
         ex = GraphExecutor(g, choices)
         ref = bnn_model.packed_forward(packed, spec, x)
         np.testing.assert_array_equal(np.asarray(ex(x)), np.asarray(ref))
@@ -418,7 +423,9 @@ class TestEngineGraphPath:
                                              batch_size=3)
         engine.cross_check(x)
         assert all(r["backend"] in runtime.BACKENDS
+                   + (runtime.IMAGE_BACKEND,)
                    for r in engine.backend_choices)
+        assert engine.backend_choices[0]["backend"] == runtime.IMAGE_BACKEND
 
     def test_artifact_graph_roundtrip_all_backends(self, tiny, tmp_path):
         """save_artifact → load_artifact → graph lowering → executor is

@@ -344,9 +344,12 @@ def _scope_set(op_maps):
 
 
 def _chain_engine(mode="vpu_chain"):
+    # The first conv runs on the MXU outside every chain; conv2+pool and
+    # conv3 form the region.
     spec = [BConv(3, 16, kernel=3, stride=1, pad=1, first=True),
             BConv(16, 16, kernel=3, stride=1, pad=1),
-            Pool(2, 2), FloatDense(8 * 8 * 16, 4)]
+            Pool(2, 2), BConv(16, 16, kernel=3, stride=1, pad=1),
+            FloatDense(8 * 8 * 16, 4)]
     params = bnn_model.init_params(jax.random.key(1), spec)
     return PhoneBitEngine.from_trained(params, spec, (16, 16),
                                        matmul_mode=mode)
@@ -420,6 +423,9 @@ class TestNodeScopes:
             assert "region." + "+".join(map(str, chain.node_ids)) in found
             assert not any(sc.startswith(f"n{nid}.") for sc in found
                            for nid in chain.node_ids)
+        # The MXU first conv keeps its node scope; no expansion runs.
+        assert "n2.packed_conv" in found
+        assert not any(sc.endswith(".bitplane_expand") for sc in found)
         ref = _chain_engine("xla").compile(1)(x)
         np.testing.assert_array_equal(got, np.asarray(ref))
 
@@ -555,8 +561,10 @@ class TestRuntimeSeries:
 
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
                            str(tmp_path / "cache.json"))
+        # conv2 is timed; the first conv takes the MXU lowering untimed.
         spec = [BConv(3, 16, kernel=3, stride=1, pad=1, first=True),
-                Pool(2, 2), FloatDense(8 * 8 * 16, 4)]
+                Pool(2, 2), BConv(16, 16, kernel=3, stride=1, pad=1),
+                FloatDense(8 * 8 * 16, 4)]
         params = bnn_model.init_params(jax.random.key(3), spec)
         eng = PhoneBitEngine.from_trained(params, spec, (16, 16),
                                           matmul_mode="auto")

@@ -15,10 +15,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.layer_integration import IntegratedParams
 from repro.kernels.bitplane_pack import bitplane_pack
 from repro.kernels.chain_conv import StageSpec, chain_conv
 from repro.kernels.direct_conv_bn_binarize import direct_conv_bn_binarize
 from repro.kernels.fused_conv_bn_binarize import fused_matmul_bn_binarize
+from repro.kernels.image_conv import column_group, image_conv_bn_binarize
 from repro.kernels.mxu_pm1_matmul import mxu_pm1_matmul
 from repro.kernels.xnor_popcount_matmul import xnor_popcount_matmul
 from repro.runtime import regions
@@ -101,24 +103,54 @@ def test_direct_conv_bn_binarize_alexnet_conv2(one_chip, pool):
 
 
 def test_chain_conv_yolo_first_region_416(one_chip):
-    """conv1(+pool1) -> conv2(+pool2) on the 8 bit-plane words of a
-    416x416 RGB frame: the first region the partitioner forms for
-    YOLOv2-Tiny at its published resolution, at the tile it resolves."""
-    stages = (StageSpec("conv", 3, 1, 1, 1, channels=16, first=True),
-              StageSpec("pool", 2, 2, channels=16),
-              StageSpec("conv", 3, 1, 1, 1, channels=32),
-              StageSpec("pool", 2, 2, channels=32))
-    in_shape = (1, 416, 416, 8)
+    """conv2(+pool2) -> conv3(+pool3) -> conv4(+pool4) on the packed
+    208x208 conv1 output of a 416x416 frame: the first region the
+    partitioner forms for YOLOv2-Tiny at its published resolution (conv1
+    runs on the MXU, outside every chain), at the tile it resolves."""
+    stages = (StageSpec("conv", 3, 1, 1, 1, channels=32),
+              StageSpec("pool", 2, 2, channels=32),
+              StageSpec("conv", 3, 1, 1, 1, channels=64),
+              StageSpec("pool", 2, 2, channels=64),
+              StageSpec("conv", 3, 1, 1, 1, channels=128),
+              StageSpec("pool", 2, 2, channels=128))
+    in_shape = (1, 208, 208, 1)
     tiling = regions.plan_chain(stages, in_shape)
-    assert tiling.tile["block_h"] < 104   # 416^2 is tiled into row bands
+    assert tiling.tile["block_h"] < 26    # 208^2 is tiled into row bands
 
-    def fn(x, w1, ww1, t1, s1, w2, t2, s2):
-        return chain_conv(x, stages, (w1, ww1, t1, s1, w2, None, t2, s2),
+    def fn(x, w1, t1, s1, w2, t2, s2, w3, t3, s3):
+        return chain_conv(x, stages, (w1, None, t1, s1, w2, None, t2, s2,
+                                      w3, None, t3, s3),
                           **tiling.kernel_args())
 
-    _compile(fn, one_chip, (in_shape, I32), ((16, 72), I32), ((72,), I32),
-             ((16,), I32), ((16,), I32), ((32, 9), I32), ((32,), I32),
-             ((32,), I32), name="chain_region")
+    _compile(fn, one_chip, (in_shape, I32), ((32, 9), I32), ((32,), I32),
+             ((32,), I32), ((64, 9), I32), ((64,), I32), ((64,), I32),
+             ((128, 18), I32), ((128,), I32), ((128,), I32),
+             name="chain_region")
+
+
+@pytest.mark.parametrize("shape,k,stride,pad,o,pool", [
+    ((8, 227, 227, 3), 11, 4, 0, 96, (3, 2, (0, 0))),   # AlexNet conv1
+    ((1, 416, 416, 3), 3, 1, 1, 16, (2, 2, (0, 0))),    # YOLOv2-Tiny conv1
+])
+def test_image_conv_first_layers(one_chip, shape, k, stride, pad, o, pool):
+    """The first binary conv on the MXU, straight from the uint8 image,
+    as the two served first layers lower: one XLA convolution (no Pallas
+    kernel) with the threshold, pool and pack after it."""
+    group = column_group(o, (shape[2] + 2 * pad - k) // stride + 1)
+    span = stride * group
+    blocks = -(-(stride * (group - 1) + k) // span)
+
+    def fn(x, w, c1, t, s):
+        return image_conv_bn_binarize(x, w, c1, IntegratedParams(t, s),
+                                      kernel=k, stride=stride, pad=pad,
+                                      pool=pool)
+
+    args = [jax.ShapeDtypeStruct(sh, d, sharding=one_chip) for sh, d in (
+        (shape, jnp.uint8), ((k, blocks, span * 3, group * o), jnp.bfloat16),
+        ((o,), I32), ((o,), I32), ((o,), jnp.bool_))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(r"= \S+ convolution\(", text)
+    assert "custom-call" not in text
 
 
 def test_bitplane_pack_416(one_chip):
