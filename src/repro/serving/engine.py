@@ -254,7 +254,8 @@ class PhoneBitEngine:
                                 data_parallel: int) -> None:
         """Publish runtime-wide memory series for a freshly built bucket:
         the arena plan's peak and, for region-fused executors, the HBM
-        round-trip traffic the chains keep in VMEM (DESIGN.md §10.2)."""
+        round-trip traffic the chains keep in VMEM and the largest
+        region's VMEM plan at the tile it serves (DESIGN.md §10.2)."""
         from repro import runtime
 
         reg = _obs_metrics.get_registry()
@@ -264,6 +265,8 @@ class PhoneBitEngine:
         if getattr(exe, "regions", None):
             reg.gauge("runtime.chain_hbm_bytes_avoided").set(
                 sum(c.hbm_bytes_avoided() for c in exe.regions))
+            reg.gauge("runtime.chain_vmem_bytes").set(
+                max(c.tiling().vmem.total_bytes() for c in exe.regions))
 
     @property
     def _executor(self):

@@ -553,6 +553,17 @@ class TestRuntimeSeries:
             assert reg.counter("runtime.retraces").value == 1
             assert reg.gauge("runtime.arena_peak_bytes").value > 0
 
+    def test_chain_vmem_gauge(self):
+        """A vpu_chain bucket publishes its largest region's VMEM plan at
+        the tile it serves; a bucket without regions leaves it unset."""
+        with metrics.use_registry() as reg:
+            _chain_engine("xla").compile(1)
+            assert reg.gauge("runtime.chain_vmem_bytes").value is None
+            exe = _chain_engine().compile(1)
+            assert exe.regions
+            assert reg.gauge("runtime.chain_vmem_bytes").value == max(
+                c.tiling().vmem.total_bytes() for c in exe.regions) > 0
+
     def test_autotune_events(self, tmp_path, monkeypatch):
         """The structured autotune audit trail: fresh sweeps emit miss
         events with a sweep size, a second engine over the same graph

@@ -8,6 +8,7 @@ The topology is described inside a fixture, only once a test of this file
 runs; the TPU compiler is loaded by whichever worker runs this file.
 """
 
+import itertools
 import re
 
 import jax
@@ -128,9 +129,61 @@ def test_chain_conv_yolo_first_region_416(one_chip):
              name="chain_region")
 
 
+@pytest.fixture(scope="module")
+def vgg16_regions():
+    """The VGG16 conv stack at 224^2 lowered as the engine lowers it, from
+    parameter shapes alone, and the regions the partitioner forms at
+    bucket 8."""
+    from repro.core import bnn_model, converter
+    from repro.core.bnn_model import BConv, Pool
+    from repro.models import paper_nets
+    from repro.runtime import graph as rgraph
+    from repro.runtime import passes
+
+    spec, (h, w, _) = paper_nets.get("vgg16")
+    spec = list(itertools.takewhile(lambda l: isinstance(l, (BConv, Pool)),
+                                    spec))
+    params = jax.eval_shape(lambda k: bnn_model.init_params(k, spec),
+                            jax.random.key(0))
+    packed = jax.eval_shape(lambda p: converter.convert(p, spec, (h, w)),
+                            params)
+    g = passes.fuse_pool_epilogue(rgraph.lower_packed(spec, packed, (h, w)))
+    return g, regions.partition_chains(g, (8, h, w, 3))
+
+
+def test_vgg16_regions_224(vgg16_regions):
+    _, chains = vgg16_regions
+    assert [c.node_ids for c in chains] == [(4, 5, 7, 8, 9),
+                                            (11, 12, 13, 15, 16, 17, 19)]
+
+
+@pytest.mark.parametrize("k", [0, 1])
+def test_chain_conv_vgg16_regions_224(one_chip, vgg16_regions, k):
+    """Each region the partitioner forms for VGG16 at bucket 8 (conv1_2
+    through conv3_2 entering at 224^2, conv3_3 through conv5_3 entering at
+    56^2: two full-resolution convs back to back) compiles at the tile
+    ``plan_chain`` resolves."""
+    g, chains = vgg16_regions
+    chain = chains[k]
+    arrays = regions.chain_stage_arrays(
+        chain, {str(n): g.nodes[n].params for n in chain.node_ids})
+    present = [a is not None for a in arrays]
+    kw = chain.tiling().kernel_args()
+
+    def fn(x, *given):
+        it = iter(given)
+        arrs = tuple(next(it) if p else None for p in present)
+        return chain_conv(x, chain.stages, arrs, **kw)
+
+    _compile(fn, one_chip, (chain.in_shape, I32),
+             *[(a.shape, a.dtype) for a in arrays if a is not None],
+             name="chain_region")
+
+
 @pytest.mark.parametrize("shape,k,stride,pad,o,pool", [
     ((8, 227, 227, 3), 11, 4, 0, 96, (3, 2, (0, 0))),   # AlexNet conv1
     ((1, 416, 416, 3), 3, 1, 1, 16, (2, 2, (0, 0))),    # YOLOv2-Tiny conv1
+    ((8, 224, 224, 3), 3, 1, 1, 64, None),              # VGG16 conv1_1
 ])
 def test_image_conv_first_layers(one_chip, shape, k, stride, pad, o, pool):
     """The first binary conv on the MXU, straight from the uint8 image,
